@@ -81,5 +81,7 @@ def run(fast: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     for r in run():
         print(",".join(map(str, r)))

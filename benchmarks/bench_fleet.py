@@ -133,6 +133,8 @@ def run(fast: bool = False, out_path: str = None) -> list:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="small trace + analytic accuracy proxy (CI smoke)")

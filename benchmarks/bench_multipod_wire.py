@@ -30,8 +30,8 @@ def _measure(mesh_shape=(2, 4, 4), batch=32, seq=2048, n_micro=4):
     from repro.models import transformer as T
 
     cfg = get_config("llama3-8b")
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat(mesh_shape, ("pod", "data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh(mesh_shape, ("pod", "data", "model"))
     pstruct = jax.eval_shape(lambda k: T.init_params(k, cfg),
                              jax.ShapeDtypeStruct((2,), jnp.uint32))
     ae_struct = jax.eval_shape(

@@ -6,8 +6,9 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 The paper's head/bottleneck/tail triple mapped onto a 2-pod mesh: the cut
 becomes the cross-pod stage boundary, the bottleneck compresses the
 activation crossing the inter-pod link, and `lax.ppermute` is the wire.
-Runs on 8 emulated host devices as a (pod=2, data=2, model=2) mesh and
-validates the pipelined output against the single-program forward.
+Runs on whatever even number of devices it is given (8 emulated host
+devices by default on the CPU) as a (pod=2, data=n/2) mesh and validates
+the pipelined output against the single-program forward.
 
 Run:  PYTHONPATH=src python examples/multipod_pipeline.py
 """
@@ -26,9 +27,11 @@ from repro.models.common import reduced
 
 
 def main():
-    assert len(jax.devices()) >= 8, "needs --xla_force_host_platform_device_count=8"
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((2, 2, 2), ("pod", "data", "model"))
+    n = len(jax.devices())
+    if n < 2 or n % 2:
+        raise SystemExit(f"needs an even number of devices (>= 2), got {n}")
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, n // 2), ("pod", "data"))
     cfg = reduced(get_config("llama3-8b"), n_layers=4, dtype="float32")
     params = T.init_params(jax.random.PRNGKey(0), cfg)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0, cfg.vocab)
@@ -56,4 +59,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -26,12 +26,6 @@ from repro.models.layered import LayeredModel
 from repro.models import transformer as T
 from repro.core import bottleneck as B
 
-if hasattr(jax, "shard_map"):
-    _shard_map, _SMAP_KW = jax.shard_map, {"check_vma": False}
-else:  # jax <= 0.4.x keeps it in experimental, with check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SMAP_KW = {"check_rep": False}
-
 
 @dataclass(frozen=True)
 class SplitPlan:
@@ -276,7 +270,7 @@ def multipod_split_step(params, cfg, batch: dict, mesh, *, ae: Optional[dict],
         valid = jnp.where(stage_id == 1, 1.0, 0.0).astype(logits.dtype)
         return jax.lax.psum(logits * valid, "pod")
 
-    f = _shard_map(pipeline, mesh=mesh,
-                   in_specs=(stage_spec, P()), out_specs=out_spec,
-                   **_SMAP_KW)
+    f = jax.shard_map(pipeline, mesh=mesh,
+                      in_specs=(stage_spec, P()), out_specs=out_spec,
+                      check_vma=False)
     return f(stages, tokens)

@@ -27,11 +27,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def tpu_available() -> bool:
-    """True when the default backend is a real TPU (not interpret mode)."""
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:
-        return False
+    """True when the default backend is a real TPU (not interpret mode).
+
+    A TPU that fails to initialise raises here: it must never quietly
+    route the codec to the CPU reference.
+    """
+    return jax.devices()[0].platform == "tpu"
 
 
 def resolve_backend(backend: str | None = None) -> str:
@@ -51,9 +52,30 @@ def resolve_backend(backend: str | None = None) -> str:
     return backend
 
 
-def _compiler_params(semantics: tuple = ("parallel", "arbitrary")):
-    cp = getattr(pltpu, "CompilerParams", None) or getattr(pltpu, "TPUCompilerParams")
-    return cp(dimension_semantics=semantics)
+# Mosaic gives a kernel 16 MiB of scoped VMEM unless asked for more; a
+# v5e core has 128 MiB.  Codec blocks are sized to stay under the budget,
+# and the limit is raised (never past the cap) only for blocks that need it.
+_VMEM_DEFAULT = 16 * 2**20
+_VMEM_BUDGET = 32 * 2**20
+_VMEM_CAP = 96 * 2**20
+_LANE = 128
+
+
+def _fit_lane_block(bc: int, vmem_bytes) -> int:
+    """Halve the lane block ``bc`` (keeping it a multiple of 128 that
+    divides the old one) until ``vmem_bytes(bc)`` fits the VMEM budget."""
+    while bc % (2 * _LANE) == 0 and vmem_bytes(bc) > _VMEM_BUDGET:
+        bc //= 2
+    return bc
+
+
+def _compiler_params(semantics: tuple = ("parallel", "arbitrary"),
+                     vmem_bytes: int = 0):
+    limit = None
+    if vmem_bytes > _VMEM_DEFAULT * 3 // 4:
+        limit = min(2 * vmem_bytes, _VMEM_CAP)
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=limit)
 
 
 def _kernel(f_ref, w_ref, b_ref, q_ref, s_ref, acc, *, nc: int, scale: float):
@@ -83,10 +105,19 @@ def bottleneck_compress(f: jax.Array, w: jax.Array, b: jax.Array, *,
     """f: (N, C) activations; w: (C, L); b: (L,).
 
     Returns (q int8 (N, L), row scales f32 (N, 1)) — the wire payload.
+    The contraction block ``bc`` shrinks for wide latents so the
+    double-buffered ``(bc, L)`` weight block fits VMEM.
     """
     n, c = f.shape
     l = w.shape[1]
-    bn_, bc_ = min(bn, n), min(bc, c)
+    lp = _pad_to(l, _LANE)
+
+    def vmem_bytes(bc_):
+        return (2 * bn_ * bc_ * 4 + 2 * bc_ * lp * 4 + 2 * 8 * lp * 4
+                + 2 * bn_ * lp + 2 * bn_ * _LANE * 4 + bn_ * lp * 4)
+
+    bn_ = min(bn, n)
+    bc_ = _fit_lane_block(min(bc, c), vmem_bytes)
     assert n % bn_ == 0 and c % bc_ == 0
     nn, nc = n // bn_, c // bc_
 
@@ -97,7 +128,7 @@ def bottleneck_compress(f: jax.Array, w: jax.Array, b: jax.Array, *,
         in_specs=[
             pl.BlockSpec((bn_, bc_), lambda i, j: (i, j)),
             pl.BlockSpec((bc_, l), lambda i, j: (j, 0)),
-            pl.BlockSpec((l,), lambda i, j: (0,)),
+            pl.BlockSpec((1, l), lambda i, j: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((bn_, l), lambda i, j: (i, 0)),
@@ -108,9 +139,9 @@ def bottleneck_compress(f: jax.Array, w: jax.Array, b: jax.Array, *,
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bn_, l), jnp.float32)],
-        compiler_params=_compiler_params(),
+        compiler_params=_compiler_params(vmem_bytes=vmem_bytes(bc_)),
         interpret=interpret,
-    )(f, w, b)
+    )(f, w, b.reshape(1, l))
     return q, s
 
 

@@ -26,9 +26,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from .bottleneck_compress import _compiler_params, _pad_to, resolve_backend
+from .bottleneck_compress import (_LANE, _compiler_params, _fit_lane_block,
+                                  _pad_to, resolve_backend)
 
 
 def _kernel(q_ref, s_ref, w_ref, b_ref, o_ref):
@@ -43,11 +43,20 @@ def bottleneck_decompress(q: jax.Array, s: jax.Array, w: jax.Array,
                           interpret: bool = False) -> jax.Array:
     """q: (N, L) int8 codes; s: (N, 1) f32 row scales; w: (L, C); b: (C,).
 
-    Returns the reconstructed f32 boundary activation (N, C).
+    Returns the reconstructed f32 boundary activation (N, C).  The output
+    block ``bc`` shrinks for wide latents so the double-buffered
+    ``(L, bc)`` decoder slab fits VMEM.
     """
     n, l = q.shape
     c = w.shape[1]
-    bn_, bc_ = min(bn, n), min(bc, c)
+    lp = _pad_to(l, _LANE)
+
+    def vmem_bytes(bc_):
+        return (2 * bn_ * lp + 2 * bn_ * _LANE * 4 + 2 * _pad_to(l, 8) * bc_ * 4
+                + 2 * 8 * bc_ * 4 + 2 * bn_ * bc_ * 4 + bn_ * (lp + bc_) * 4)
+
+    bn_ = min(bn, n)
+    bc_ = _fit_lane_block(min(bc, c), vmem_bytes)
     assert n % bn_ == 0 and c % bc_ == 0
     nn, nc = n // bn_, c // bc_
 
@@ -58,13 +67,14 @@ def bottleneck_decompress(q: jax.Array, s: jax.Array, w: jax.Array,
             pl.BlockSpec((bn_, l), lambda i, j: (i, 0)),
             pl.BlockSpec((bn_, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((l, bc_), lambda i, j: (0, j)),
-            pl.BlockSpec((bc_,), lambda i, j: (j,)),
+            pl.BlockSpec((1, bc_), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((bn_, bc_), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, c), jnp.float32),
-        compiler_params=_compiler_params(("parallel", "parallel")),
+        compiler_params=_compiler_params(("parallel", "parallel"),
+                                         vmem_bytes(bc_)),
         interpret=interpret,
-    )(q, s, w, b)
+    )(q, s, w, b.reshape(1, c))
 
 
 def bottleneck_decompress_any(q: jax.Array, s: jax.Array, w: jax.Array,

@@ -26,8 +26,7 @@ NEG_INF = -1e30
 
 
 def _compiler_params():
-    cp = getattr(pltpu, "CompilerParams", None) or getattr(pltpu, "TPUCompilerParams")
-    return cp(dimension_semantics=("parallel", "parallel", "arbitrary"))
+    return pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,

@@ -25,8 +25,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _compiler_params():
-    cp = getattr(pltpu, "CompilerParams", None) or getattr(pltpu, "TPUCompilerParams")
-    return cp(dimension_semantics=("parallel", "parallel", "arbitrary"))
+    return pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _kernel(dt_ref, b_ref, c_ref, x_ref, a_ref, y_ref, state, *, chunk: int):

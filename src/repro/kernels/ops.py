@@ -9,25 +9,16 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
-
 from . import ref
-from .bottleneck_compress import bottleneck_compress
+from .bottleneck_compress import bottleneck_compress, tpu_available
 from .flash_attention import flash_attention
 from .mamba_scan import mamba_scan
 from .rwkv6_scan import rwkv6_scan
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
-
-
 def attention_op(q, k, v, *, causal: bool = True, window: Optional[int] = None,
                  force: Optional[str] = None):
-    mode = force or ("pallas" if _on_tpu() else "ref")
+    mode = force or ("pallas" if tpu_available() else "ref")
     if mode == "pallas":
         return flash_attention(q, k, v, causal=causal, window=window)
     if mode == "pallas-interpret":
@@ -37,7 +28,7 @@ def attention_op(q, k, v, *, causal: bool = True, window: Optional[int] = None,
 
 
 def compress_op(f, w, b, *, force: Optional[str] = None):
-    mode = force or ("pallas" if _on_tpu() else "ref")
+    mode = force or ("pallas" if tpu_available() else "ref")
     if mode == "pallas":
         return bottleneck_compress(f, w, b)
     if mode == "pallas-interpret":
@@ -50,7 +41,7 @@ def decompress_op(q, s):
 
 
 def wkv_op(r, k, v, w, u, *, chunk: int = 64, force: Optional[str] = None):
-    mode = force or ("pallas" if _on_tpu() else "ref")
+    mode = force or ("pallas" if tpu_available() else "ref")
     if mode == "pallas":
         return rwkv6_scan(r, k, v, w, u, chunk=chunk)
     if mode == "pallas-interpret":
@@ -61,7 +52,7 @@ def wkv_op(r, k, v, w, u, *, chunk: int = 64, force: Optional[str] = None):
 
 
 def mamba_scan_op(dt, b, c, x, a, *, force=None):
-    mode = force or ("pallas" if _on_tpu() else "ref")
+    mode = force or ("pallas" if tpu_available() else "ref")
     if mode == "pallas":
         return mamba_scan(dt, b, c, x, a)
     if mode == "pallas-interpret":

@@ -21,8 +21,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _compiler_params():
-    cp = getattr(pltpu, "CompilerParams", None) or getattr(pltpu, "TPUCompilerParams")
-    return cp(dimension_semantics=("parallel", "arbitrary"))
+    return pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
 
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, sf_ref, state, *,

@@ -104,7 +104,7 @@ def run_case(arch: str, shape_name: str, *, multi_pod: bool = False,
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
     ma = compiled.memory_analysis()
-    ca = compiled.cost_analysis() or {}
+    ca = compiled.cost_analysis()
     hlo = compiled.as_text()
     from repro.launch.hlo_cost import HloCost
     hc = HloCost(hlo)

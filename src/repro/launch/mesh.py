@@ -8,24 +8,12 @@ from __future__ import annotations
 import jax
 
 
-def _mesh_kwargs(n_axes: int) -> dict:
-    """``axis_types`` only exists on newer jax; older versions (<=0.4.x)
-    default to auto sharding anyway, so omit it there."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
-def make_mesh_compat(shape: tuple, axes: tuple):
-    """``jax.make_mesh`` with Auto axis types where the jax version has
-    them — use this instead of calling ``jax.make_mesh`` directly.  Falls
-    back to ``mesh_utils`` + ``Mesh`` on jax versions predating
-    ``jax.make_mesh``."""
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
-    from jax.experimental import mesh_utils
-    return jax.sharding.Mesh(mesh_utils.create_device_mesh(shape), axes)
+def make_mesh(shape: tuple, axes: tuple, *, devices=None):
+    """``jax.make_mesh`` with Auto axis types (its default is Explicit),
+    which the sharding rules and ``shard_map`` steps are written for."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False, model_axis: int = 16):
@@ -41,9 +29,9 @@ def make_production_mesh(*, multi_pod: bool = False, model_axis: int = 16):
     data = 256 // model_axis
     shape = (2, data, model_axis) if multi_pod else (data, model_axis)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Degenerate 1-device mesh for CPU smoke tests."""
-    return jax.make_mesh((1, 1), ("data", "model"), **_mesh_kwargs(2))
+    return make_mesh((1, 1), ("data", "model"))

@@ -200,11 +200,11 @@ def _header(kind: str, shape: tuple, *, crcs: Optional[tuple] = None) -> bytes:
 
 def _buffer_view(a, dtype) -> memoryview:
     """A C-contiguous byte view over ``a`` without copying when possible
-    (device arrays on CPU backends and contiguous numpy arrays alias)."""
-    arr = np.asarray(a, dtype)
-    if not arr.flags.c_contiguous:
-        arr = np.ascontiguousarray(arr)
-    return memoryview(arr).cast("B", (arr.nbytes,))
+    (device arrays on CPU backends and contiguous numpy arrays alias).
+    Viewing through ``uint8`` keeps the empty array (a batch of 0) legal,
+    where ``memoryview.cast`` refuses a zero in the shape."""
+    arr = np.ascontiguousarray(np.asarray(a, dtype))
+    return memoryview(arr.reshape(-1).view(np.uint8))
 
 
 def frame_arrays(kind: str, data, scales=None, *, checksum: bool = False) -> bytes:

@@ -1,5 +1,9 @@
 """Multi-pod split pipeline correctness (runs in a subprocess because the
-device-count flag must be set before jax initialises)."""
+device-count flag must be set before jax initialises).
+
+CPU only: the parent has imported JAX before it starts the child, and on a
+TPU host the parent would hold the chip the child needs.  On the chip the
+same path runs in one process: ``python chip_smoke.py --chips 4``."""
 import os
 import subprocess
 import sys

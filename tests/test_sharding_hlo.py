@@ -99,10 +99,7 @@ def test_hlo_cost_counts_scan_trip():
     hc = HloCost(c.as_text())
     exact = 8 * L * B * D * D   # fwd + recompute + 2 bwd matmuls
     assert abs(hc.flops - exact) / exact < 0.05
-    ca = c.cost_analysis()
-    if isinstance(ca, (list, tuple)):      # older jax: one dict per partition
-        ca = ca[0]
-    raw = ca["flops"]
+    raw = c.cost_analysis()["flops"]
     assert raw < exact / 2      # demonstrates why the analyzer exists
 
 
