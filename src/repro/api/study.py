@@ -219,11 +219,14 @@ class Study:
         Subsequent calls return the same live report (the recorder is
         shared, so spans and time series keep accumulating across
         stages).  Export with ``report.to_chrome_trace(path)`` and open
-        in Perfetto (https://ui.perfetto.dev).
+        in Perfetto (https://ui.perfetto.dev).  Its wall spans also enter
+        ``jax.profiler`` annotations, so a profiler trace taken while
+        observing shows them beside the device's operations.
         """
         if self._recorder is None:
             from repro.obs import Recorder
-            self._recorder = Recorder(window_s=window_s)
+            self._recorder = Recorder(window_s=window_s,
+                                      annotate=jax.profiler.TraceAnnotation)
         return self._recorder.report()
 
     @property
@@ -800,7 +803,8 @@ class Study:
             from repro.runtime.engine import TailServer
             from repro.runtime.partition import make_partition
             part = make_partition(self.model, self.params, splits, ae)
-            return TailServer(part, n_slots=n_slots, faults=faults)
+            return TailServer(part, n_slots=n_slots, faults=faults,
+                              obs=self._recorder)
         from repro.runtime.engine import SplitRuntime
         if isinstance(hops, str):            # protocol over the study link
             return SplitRuntime(self.model, self.params, splits, ae=ae,

@@ -43,13 +43,15 @@ class Recorder:
     """One tracer + one metrics registry; ``enabled`` is True.
 
     ``window_s`` is the default sampling window instrumented simulators
-    use for windowed time series (``fleet.cluster`` reads it).
+    use for windowed time series (``fleet.cluster`` reads it);
+    ``annotate`` goes to the :class:`Tracer` (its wall spans then also
+    enter the profiler's trace: pass ``jax.profiler.TraceAnnotation``).
     """
 
     enabled = True
 
-    def __init__(self, window_s: float = 0.05):
-        self.tracer = Tracer()
+    def __init__(self, window_s: float = 0.05, annotate=None):
+        self.tracer = Tracer(annotate)
         self.metrics = MetricsRegistry()
         self.window_s = window_s
 
@@ -92,7 +94,7 @@ class _NullTracer:
     def extend(self, spans) -> None:
         pass
 
-    def span(self, *a, **kw):
+    def span(self, name, *, tid="main", cat="", args=None):
         return _NULL_SPAN
 
 

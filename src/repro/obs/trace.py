@@ -63,12 +63,19 @@ class Tracer:
     passes ``EventQueue.now``); the :meth:`span` context manager times a
     wall-clock phase.  ``to_chrome_trace`` writes the Perfetto-loadable
     JSON.
+
+    ``annotate``, when given, is a callable taking a span's name and
+    returning a context manager (``jax.profiler.TraceAnnotation``): each
+    :meth:`span` also enters it for its duration, so a profiler running
+    meanwhile records the span on its own host timeline, beside the
+    device's operations.  ``add``/``instant`` never call it.
     """
 
-    def __init__(self):
+    def __init__(self, annotate=None):
         self.spans: list[Span] = []
         self._epoch = time.perf_counter()
         self._stack: list[Span] = []     # open wall-clock span() nesting
+        self.annotate = annotate
 
     def wall_now(self) -> float:
         """Seconds since this tracer's epoch (the wall timeline)."""
@@ -106,7 +113,11 @@ class Tracer:
                      cat=cat, args=args, parent=parent)
         self._stack.append(s)
         try:
-            yield s
+            if self.annotate is None:
+                yield s
+            else:
+                with self.annotate(name):
+                    yield s
         finally:
             self._stack.pop()
             s.t1 = self.wall_now()

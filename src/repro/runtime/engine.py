@@ -549,7 +549,8 @@ class SplitRuntime:
 class TailRequest:
     client_id: int
     payload: bytes                   # serialized wire packet
-    t_submit: float = 0.0
+    t_submit: float = 0.0            # on the server's recorder's wall clock
+    t_admit: float = 0.0             # (both stay 0.0 with telemetry off)
 
 
 class TailServer:
@@ -561,11 +562,22 @@ class TailServer:
     zeros, their outputs discarded).  The tail is jitted once for the pool
     shape — batch composition changes per step without recompiling, the
     same discipline ``ContinuousBatcher`` applies to decode streams.
+
+    ``obs`` (a ``repro.obs.Recorder``; the free null recorder by default)
+    times each serving step as wall spans on the ``tail_server`` track:
+    ``server.step`` holds ``server.admit``, ``server.inputs`` (one
+    ``server.frame`` per admitted request, each holding ``server.parse``,
+    ``server.decode`` and ``server.scatter``), ``server.tail`` and
+    ``server.fetch`` (the host waits there for the logits).  It also
+    records ``runtime.queue_wait_s`` (admission minus submission, per
+    request) and the ``runtime.queue_depth`` left after admission.
     """
+
+    TRACK = "tail_server"
 
     def __init__(self, part: Partition, *, n_slots: int = 4,
                  client_batch: int = 1,
-                 faults: Optional[FaultPlan] = None):
+                 faults: Optional[FaultPlan] = None, obs=None):
         self.part = part
         self.pool = SlotPool(n_slots)
         self.queue: deque = deque()
@@ -579,8 +591,9 @@ class TailServer:
         self.n_rejected = 0
         self.rejected: list = []
         self.n_blackout_steps = 0
+        self.obs = NULL if obs is None else obs
 
-    def submit(self, client_id: int, payload: bytes, t: float = 0.0) -> bool:
+    def submit(self, client_id: int, payload: bytes) -> bool:
         """Queue one wire payload.  With a fault plan installed the frame
         is integrity-checked on admission (corrupted frames are rejected
         and counted — the client's retry loop re-sends, the server never
@@ -592,8 +605,12 @@ class TailServer:
                 self.n_rejected += 1
                 self.rejected.append(client_id)
                 return False
-        self.queue.append(TailRequest(client_id, payload, t))
+        self.queue.append(TailRequest(client_id, payload,
+                                      self.obs.tracer.wall_now()))
         return True
+
+    def _span(self, name: str):
+        return self.obs.tracer.span(name, tid=self.TRACK, cat="runtime")
 
     def step(self, now: Optional[float] = None) -> dict:
         """Serve up to ``n_slots`` queued requests in one batched forward.
@@ -606,25 +623,51 @@ class TailServer:
                 and self.faults.blackout_at(now)):
             self.n_blackout_steps += 1
             return {}
-        while self.queue and self.pool.free_slots():
-            self.pool.admit(self.queue.popleft())
-        active = self.pool.occupied()
-        if not active:
+        if not (self.queue or self.pool.any_active()):
             return {}
-        fb = jnp.zeros((len(self.pool), self.client_batch) + self._feat,
-                       jnp.float32)
-        for slot, req in active:
-            f = W.decode_activation(W.from_bytes(req.payload), self.part.ae)
-            fb = fb.at[slot].set(f.astype(jnp.float32))
-        # one jitted tail forward for the whole pool (shape is static:
-        # n_slots * client_batch), reusing the partition's compiled stage
-        logits = self.part.tail(
-            fb.reshape((len(self.pool) * self.client_batch,) + self._feat))
-        logits = np.asarray(logits).reshape(
-            (len(self.pool), self.client_batch) + logits.shape[1:])
+        obs = self.obs
+        with self._span("server.step") as step_span:
+            with self._span("server.admit"):
+                t_admit = obs.tracer.wall_now()
+                while self.queue and self.pool.free_slots():
+                    req = self.queue.popleft()
+                    req.t_admit = t_admit
+                    self.pool.admit(req)
+            active = self.pool.occupied()
+            if obs.enabled:
+                wait = obs.metrics.histogram("runtime.queue_wait_s")
+                for _, req in active:
+                    wait.observe(req.t_admit - req.t_submit)
+                obs.metrics.gauge("runtime.queue_depth").set(len(self.queue))
+                step_span.args.update(admitted=len(active),
+                                      queued=len(self.queue))
+            with self._span("server.inputs"):
+                fb = jnp.zeros((len(self.pool), self.client_batch)
+                               + self._feat, jnp.float32)
+                for slot, req in active:
+                    with self._span("server.frame") as frame_span:
+                        if obs.enabled:
+                            frame_span.args.update(rid=req.client_id,
+                                                   slot=slot)
+                        with self._span("server.parse"):
+                            pkt = W.from_bytes(req.payload)
+                        with self._span("server.decode"):
+                            f = W.decode_activation(pkt, self.part.ae)
+                        with self._span("server.scatter"):
+                            fb = fb.at[slot].set(f.astype(jnp.float32))
+            # one jitted tail forward for the whole pool (shape is static:
+            # n_slots * client_batch), reusing the partition's compiled stage
+            with self._span("server.tail"):
+                logits = self.part.tail(
+                    fb.reshape((len(self.pool) * self.client_batch,)
+                               + self._feat))
+            with self._span("server.fetch"):
+                host = np.asarray(logits)
+        host = host.reshape((len(self.pool), self.client_batch)
+                            + host.shape[1:])
         out = {}
         for slot, req in active:
-            out[req.client_id] = logits[slot]
+            out[req.client_id] = host[slot]
             self.pool.release(slot)
         self.n_batches += 1
         self.n_served += len(active)
