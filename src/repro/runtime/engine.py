@@ -553,24 +553,47 @@ class TailRequest:
     t_admit: float = 0.0             # (both stay 0.0 with telemetry off)
 
 
+@dataclass
+class _Staging:
+    """Host staging of one payload layout: every slot's codes (and row
+    scales, for the int8 kinds) side by side in the order of the slots,
+    so one step uploads the whole pool in one transfer."""
+    kind: str
+    shape: tuple                     # one request's payload shape
+    codes: np.ndarray                # (n_slots * client_batch, *shape[1:])
+    scales: Optional[np.ndarray]     # (n_slots * rows per request, 1) f32
+
+    @property
+    def nbytes(self) -> int:
+        return self.codes.nbytes + (0 if self.scales is None
+                                    else self.scales.nbytes)
+
+
 class TailServer:
     """Server side of the split runtime under N edge clients.
 
     Requests (wire byte strings) queue; each :meth:`step` admits up to
-    ``n_slots`` of them into the slot pool, decodes, and runs **one**
-    batched tail forward for the whole pool (empty slots padded with
-    zeros, their outputs discarded).  The tail is jitted once for the pool
-    shape — batch composition changes per step without recompiling, the
-    same discipline ``ContinuousBatcher`` applies to decode streams.
+    ``n_slots`` of them into the slot pool, copies each frame's codes and
+    scales into its slot's rows of a host staging buffer, uploads the
+    whole pool in one transfer and runs **one** program for it: the wire
+    decode as the prologue of the batched tail
+    (``Partition.served_tail``).  Empty slots keep the codes they last
+    held and their outputs are discarded.  The program compiles once for
+    the pool shape — batch composition changes per step without
+    recompiling, the same discipline ``ContinuousBatcher`` applies to
+    decode streams.  The pool's payload layout (kind and per-request
+    shape) is set by the first frame staged; a frame of another layout
+    raises :class:`~repro.runtime.wire.WireError` and leaves the pool.
 
     ``obs`` (a ``repro.obs.Recorder``; the free null recorder by default)
     times each serving step as wall spans on the ``tail_server`` track:
     ``server.step`` holds ``server.admit``, ``server.inputs`` (one
-    ``server.frame`` per admitted request, each holding ``server.parse``,
-    ``server.decode`` and ``server.scatter``), ``server.tail`` and
-    ``server.fetch`` (the host waits there for the logits).  It also
-    records ``runtime.queue_wait_s`` (admission minus submission, per
-    request) and the ``runtime.queue_depth`` left after admission.
+    ``server.frame`` per admitted request, each holding ``server.parse``
+    and ``server.stage``, then one ``server.upload`` of the pool),
+    ``server.tail`` and ``server.fetch`` (the host waits there for the
+    logits).  It also counts ``runtime.frames_staged`` and records
+    ``runtime.queue_wait_s`` (admission minus submission, per request)
+    and the ``runtime.queue_depth`` left after admission.
     """
 
     TRACK = "tail_server"
@@ -592,6 +615,7 @@ class TailServer:
         self.rejected: list = []
         self.n_blackout_steps = 0
         self.obs = NULL if obs is None else obs
+        self._staging: Optional[_Staging] = None
 
     def submit(self, client_id: int, payload: bytes) -> bool:
         """Queue one wire payload.  With a fault plan installed the frame
@@ -612,6 +636,37 @@ class TailServer:
     def _span(self, name: str):
         return self.obs.tracer.span(name, tid=self.TRACK, cat="runtime")
 
+    def _staging_for(self, pkt: W.WirePacket) -> _Staging:
+        """The staging buffers of ``pkt``'s payload kind: the boundary
+        shape, or the AE latent's width for 'ae8'."""
+        kind = pkt.kind
+        shape = (self.client_batch,) + self._feat
+        if kind == "ae8":
+            if self.part.ae is None:
+                raise W.WireError("ae8 frame, but the partition has no "
+                                  "bottleneck AE to decode it")
+            shape = shape[:-1] + (self.part.ae["enc"]["w"].shape[-1],)
+        n = len(self.pool)
+        rows = int(np.prod(shape[:-1]))
+        return _Staging(
+            kind, shape,
+            np.zeros((n * shape[0],) + shape[1:], pkt.data.dtype),
+            None if kind == "f32" else np.zeros((n * rows, 1), np.float32))
+
+    def _stage(self, slot: int, pkt: W.WirePacket) -> None:
+        """Copy one parsed frame into ``slot``'s rows of the staging."""
+        if self._staging is None:
+            self._staging = self._staging_for(pkt)
+        st = self._staging
+        if (pkt.kind, tuple(pkt.shape)) != (st.kind, st.shape):
+            raise W.WireError(f"frame layout {pkt.kind} {tuple(pkt.shape)} "
+                              f"is not the pool's {st.kind} {st.shape}")
+        b = st.shape[0]
+        np.copyto(st.codes[slot * b:(slot + 1) * b], pkt.data)
+        if st.scales is not None:
+            r = len(pkt.scales)
+            np.copyto(st.scales[slot * r:(slot + 1) * r], pkt.scales)
+
     def step(self, now: Optional[float] = None) -> dict:
         """Serve up to ``n_slots`` queued requests in one batched forward.
 
@@ -629,21 +684,21 @@ class TailServer:
         with self._span("server.step") as step_span:
             with self._span("server.admit"):
                 t_admit = obs.tracer.wall_now()
+                admitted = []
                 while self.queue and self.pool.free_slots():
                     req = self.queue.popleft()
                     req.t_admit = t_admit
                     self.pool.admit(req)
+                    admitted.append(req)
             active = self.pool.occupied()
             if obs.enabled:
                 wait = obs.metrics.histogram("runtime.queue_wait_s")
-                for _, req in active:
+                for req in admitted:
                     wait.observe(req.t_admit - req.t_submit)
                 obs.metrics.gauge("runtime.queue_depth").set(len(self.queue))
                 step_span.args.update(admitted=len(active),
                                       queued=len(self.queue))
             with self._span("server.inputs"):
-                fb = jnp.zeros((len(self.pool), self.client_batch)
-                               + self._feat, jnp.float32)
                 for slot, req in active:
                     with self._span("server.frame") as frame_span:
                         if obs.enabled:
@@ -651,16 +706,32 @@ class TailServer:
                                                    slot=slot)
                         with self._span("server.parse"):
                             pkt = W.from_bytes(req.payload)
-                        with self._span("server.decode"):
-                            f = W.decode_activation(pkt, self.part.ae)
-                        with self._span("server.scatter"):
-                            fb = fb.at[slot].set(f.astype(jnp.float32))
-            # one jitted tail forward for the whole pool (shape is static:
-            # n_slots * client_batch), reusing the partition's compiled stage
+                        with self._span("server.stage"):
+                            try:
+                                self._stage(slot, pkt)
+                            except W.WireError:
+                                # the frame leaves the pool; the others
+                                # stay admitted for the next step
+                                self.pool.release(slot)
+                                self.n_rejected += 1
+                                self.rejected.append(req.client_id)
+                                raise
+                st = self._staging
+                # one transfer of the whole pool; a copy, so the staging
+                # can be rewritten while a device array of it lives
+                with self._span("server.upload") as upload_span:
+                    boundary = jax.device_put((st.codes, st.scales),
+                                              may_alias=False)
+                    if obs.enabled:
+                        upload_span.args.update(frames=len(active),
+                                                bytes=st.nbytes)
+                if obs.enabled:
+                    obs.metrics.counter("runtime.frames_staged").inc(
+                        len(active))
+            # one program for the whole pool (shape is static:
+            # n_slots * client_batch): decode prologue + the tail
             with self._span("server.tail"):
-                logits = self.part.tail(
-                    fb.reshape((len(self.pool) * self.client_batch,)
-                               + self._feat))
+                logits = self.part.served_tail(st.kind)(boundary)
             with self._span("server.fetch"):
                 host = np.asarray(logits)
         host = host.reshape((len(self.pool), self.client_batch)

@@ -32,6 +32,23 @@ def _is_single_ae(ae: dict) -> bool:
     return "enc" in ae and "dec" in ae
 
 
+def _donate() -> tuple:
+    """Donate a program's boundary input where buffers can alias.
+    Donation is a no-op on hosts without buffer aliasing (CPU XLA warns
+    and ignores it), so it is only requested where it can land."""
+    return (0,) if jax.devices()[0].platform != "cpu" else ()
+
+
+def _decode_pinned(kind: str, data, scales, ae: Optional[dict],
+                   backend: Optional[str]):
+    """Boundary decode as a stage prologue.  The barrier pins the codec
+    subgraph: XLA may not fold the next stage's layers into the decode's
+    float math, which keeps the decoded activation the eager byte path's
+    (``wire._decode_jit`` compiles the same subgraph) to the bit."""
+    return jax.lax.optimization_barrier(
+        W.decode_arrays(kind, data, scales, ae, backend=backend))
+
+
 @dataclass
 class Partition:
     """Stage executables for an ordered cut list.
@@ -53,6 +70,7 @@ class Partition:
     _stages: list = field(default=None, repr=False)
     _tail: object = field(default=None, repr=False)
     _fused: dict = field(default_factory=dict, repr=False)
+    _served: dict = field(default_factory=dict, repr=False)
 
     def __post_init__(self):
         self.splits = validate_cuts(self.model, self.split_layer)
@@ -151,14 +169,10 @@ class Partition:
         bounds = (0,) + tuple(c + 1 for c in self.splits) + (len(m.layers),)
         aes = [self.ae_map.get(c) for c in self.splits]
         kinds = self.wire_kinds(quantize)
-        # Donation is a no-op on hosts without buffer aliasing (CPU XLA
-        # warns and ignores it) — only request it where it can land.
-        donate = (0,) if jax.devices()[0].platform != "cpu" else ()
+        donate = _donate()
 
-        # The barrier pins the codec subgraph: XLA may not fold stage
-        # layers into the quantiser's float math (or vice versa), which
-        # is what keeps the payload bit-identical to the eager byte path
-        # (wire._encode_jit / _decode_jit compile the same subgraph).
+        # The barriers pin the codec subgraphs (see _decode_pinned): the
+        # payload stays bit-identical to the eager byte path's.
         def pin(data, scales):
             if shard_fn is None:
                 return data, scales
@@ -173,10 +187,7 @@ class Partition:
                                         backend=backend))
 
         def dec(boundary, k):
-            data, scales = pin(*boundary)
-            return jax.lax.optimization_barrier(
-                W.decode_arrays(kinds[k], data, scales, aes[k],
-                                backend=backend))
+            return _decode_pinned(kinds[k], *pin(*boundary), aes[k], backend)
 
         n = len(self.splits)
         segs = [jax.jit(lambda x, b=bounds[1]:
@@ -205,6 +216,30 @@ class Partition:
         for seg in segs[1:]:
             cur = seg(cur)
         return cur
+
+    def served_tail(self, kind: str, *, backend: Optional[str] = None):
+        """The tail server's program for ``kind`` payloads:
+        ``(data, scales) -> logits``, the hop-0 decode (the fused
+        segments' prologue) then everything after the first cut.
+
+        ``served_tail(kind)(boundary)`` equals ``tail(decode)`` for any
+        number of cuts, where the last fused segment covers only the last
+        stage; ``data`` may stack many requests' payloads along its
+        leading axis (``scales`` their rows in the same order), so one
+        call decodes and serves a whole pool.  The boundary is donated
+        where it can be; cached per ``(kind, backend)``.  A lambda like
+        the stage jits, so its device program is named ``jit__lambda``.
+        """
+        key = (kind, backend)
+        if key not in self._served:
+            m, p, ae = self.model, self.params, self.ae
+            a = self.splits[0] + 1
+            self._served[key] = jax.jit(
+                lambda bd: m.apply_range(
+                    p, _decode_pinned(kind, *bd, ae, backend), a,
+                    len(m.layers)),
+                donate_argnums=_donate())
+        return self._served[key]
 
     # ------------------------------------------------------------ shapes ----
     def boundary_shape(self, batch: int = 1, hop: int = 0) -> tuple:

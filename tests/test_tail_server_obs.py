@@ -1,5 +1,5 @@
 """``TailServer`` telemetry: the ``server.*`` span tree of each serving
-step, the queue-wait and queue-depth entries, the profiler hook of
+step, the frames-staged counter, the queue-wait and queue-depth entries, the profiler hook of
 ``Tracer.span``, and the null recorder's silence."""
 import os
 import subprocess
@@ -16,7 +16,7 @@ from repro.runtime.partition import make_partition
 
 CLIENTS = [10, 11, 12, 13, 14]
 STEP_PARTS = ["server.admit", "server.inputs", "server.tail", "server.fetch"]
-FRAME_PARTS = ["server.parse", "server.decode", "server.scatter"]
+FRAME_PARTS = ["server.parse", "server.stage"]
 
 
 class FakeAnnotate:
@@ -96,16 +96,35 @@ def test_inputs_hold_one_frame_per_admitted_request(served):
     rids = []
     for s in _steps(rec):
         inputs = s.children[1]
-        frames = inputs.children
+        frames = inputs.children[:-1]
         assert len(frames) == s.args["admitted"]
         assert {f.name for f in frames} == {"server.frame"}
-        _nested_in_order(inputs, frames)
+        _nested_in_order(inputs, inputs.children)
         for f in frames:
             assert [c.name for c in f.children] == FRAME_PARTS
             _nested_in_order(f, f.children)
         assert [f.args["slot"] for f in frames] == list(range(len(frames)))
         rids += [f.args["rid"] for f in frames]
     assert rids == CLIENTS
+
+
+def test_inputs_end_with_one_upload_of_the_pool(served):
+    part, frames, rec, _, server, _ = served
+    pkt = W.from_bytes(frames[0])
+    pool_bytes = 2 * (pkt.data.nbytes + pkt.scales.nbytes)
+    for s in _steps(rec):
+        upload = s.children[1].children[-1]
+        assert upload.name == "server.upload" and not upload.children
+        assert upload.args == {"frames": s.args["admitted"],
+                               "bytes": pool_bytes}
+
+
+def test_frames_staged_counts_every_admitted_frame(served):
+    _, _, rec, _, server, _ = served
+    staged = rec.metrics.get("runtime.frames_staged")
+    assert staged.value == len(CLIENTS) == server.n_served
+    assert len([s for s in rec.tracer.spans
+                if s.name == "server.stage"]) == len(CLIENTS)
 
 
 def test_queue_wait_and_depth(served):
