@@ -555,10 +555,9 @@ class TailRequest:
 
 @dataclass
 class _Staging:
-    """Host staging of one payload layout: every slot's codes (and row
+    """Host staging of one payload kind: every slot's codes (and row
     scales, for the int8 kinds) side by side in the order of the slots,
     so one step uploads the whole pool in one transfer."""
-    kind: str
     shape: tuple                     # one request's payload shape
     codes: np.ndarray                # (n_slots * client_batch, *shape[1:])
     scales: Optional[np.ndarray]     # (n_slots * rows per request, 1) f32
@@ -581,19 +580,24 @@ class TailServer:
     held and their outputs are discarded.  The program compiles once for
     the pool shape — batch composition changes per step without
     recompiling, the same discipline ``ContinuousBatcher`` applies to
-    decode streams.  The pool's payload layout (kind and per-request
-    shape) is set by the first frame staged; a frame of another layout
-    raises :class:`~repro.runtime.wire.WireError` and leaves the pool.
+    decode streams.  Each payload kind (``ae8``, ``int8``, ``f32``) has
+    its own staging and program, so frames of several kinds (the codec
+    downgrade ladder's) share a step, one upload and one program per
+    kind present.  A frame that does not parse, or whose per-request
+    shape is not the partition's boundary (the AE latent for ``ae8``) at
+    ``client_batch``, raises :class:`~repro.runtime.wire.WireError` and
+    leaves the pool; the other admitted requests are served by the next
+    step.
 
     ``obs`` (a ``repro.obs.Recorder``; the free null recorder by default)
     times each serving step as wall spans on the ``tail_server`` track:
     ``server.step`` holds ``server.admit``, ``server.inputs`` (one
     ``server.frame`` per admitted request, each holding ``server.parse``
-    and ``server.stage``, then one ``server.upload`` of the pool),
-    ``server.tail`` and ``server.fetch`` (the host waits there for the
-    logits).  It also counts ``runtime.frames_staged`` and records
-    ``runtime.queue_wait_s`` (admission minus submission, per request)
-    and the ``runtime.queue_depth`` left after admission.
+    and ``server.stage``, then one ``server.upload`` of the pool per
+    kind), ``server.tail`` and ``server.fetch`` (the host waits there
+    for the logits).  It also records ``runtime.queue_wait_s``
+    (admission minus submission, per request) and the
+    ``runtime.queue_depth`` left after admission.
     """
 
     TRACK = "tail_server"
@@ -615,7 +619,7 @@ class TailServer:
         self.rejected: list = []
         self.n_blackout_steps = 0
         self.obs = NULL if obs is None else obs
-        self._staging: Optional[_Staging] = None
+        self._staging: dict = {}     # payload kind -> _Staging
 
     def submit(self, client_id: int, payload: bytes) -> bool:
         """Queue one wire payload.  With a fault plan installed the frame
@@ -636,9 +640,9 @@ class TailServer:
     def _span(self, name: str):
         return self.obs.tracer.span(name, tid=self.TRACK, cat="runtime")
 
-    def _staging_for(self, pkt: W.WirePacket) -> _Staging:
-        """The staging buffers of ``pkt``'s payload kind: the boundary
-        shape, or the AE latent's width for 'ae8'."""
+    def _stage(self, slot: int, pkt: W.WirePacket) -> None:
+        """Copy one parsed frame into ``slot``'s rows of its kind's
+        staging, after checking its shape against the partition's."""
         kind = pkt.kind
         shape = (self.client_batch,) + self._feat
         if kind == "ae8":
@@ -646,22 +650,18 @@ class TailServer:
                 raise W.WireError("ae8 frame, but the partition has no "
                                   "bottleneck AE to decode it")
             shape = shape[:-1] + (self.part.ae["enc"]["w"].shape[-1],)
-        n = len(self.pool)
-        rows = int(np.prod(shape[:-1]))
-        return _Staging(
-            kind, shape,
-            np.zeros((n * shape[0],) + shape[1:], pkt.data.dtype),
-            None if kind == "f32" else np.zeros((n * rows, 1), np.float32))
-
-    def _stage(self, slot: int, pkt: W.WirePacket) -> None:
-        """Copy one parsed frame into ``slot``'s rows of the staging."""
-        if self._staging is None:
-            self._staging = self._staging_for(pkt)
-        st = self._staging
-        if (pkt.kind, tuple(pkt.shape)) != (st.kind, st.shape):
-            raise W.WireError(f"frame layout {pkt.kind} {tuple(pkt.shape)} "
-                              f"is not the pool's {st.kind} {st.shape}")
-        b = st.shape[0]
+        if tuple(pkt.shape) != shape:
+            raise W.WireError(f"frame layout {kind} {tuple(pkt.shape)} "
+                              f"is not the pool's {kind} {shape}")
+        st = self._staging.get(kind)
+        if st is None:
+            n = len(self.pool)
+            rows = int(np.prod(shape[:-1]))
+            st = self._staging[kind] = _Staging(
+                shape, np.zeros((n * shape[0],) + shape[1:], pkt.data.dtype),
+                None if kind == "f32" else np.zeros((n * rows, 1),
+                                                    np.float32))
+        b = shape[0]
         np.copyto(st.codes[slot * b:(slot + 1) * b], pkt.data)
         if st.scales is not None:
             r = len(pkt.scales)
@@ -698,47 +698,50 @@ class TailServer:
                 obs.metrics.gauge("runtime.queue_depth").set(len(self.queue))
                 step_span.args.update(admitted=len(active),
                                       queued=len(self.queue))
+            kinds = {}                   # slot -> payload kind
             with self._span("server.inputs"):
                 for slot, req in active:
                     with self._span("server.frame") as frame_span:
                         if obs.enabled:
                             frame_span.args.update(rid=req.client_id,
                                                    slot=slot)
-                        with self._span("server.parse"):
-                            pkt = W.from_bytes(req.payload)
-                        with self._span("server.stage"):
-                            try:
+                        try:
+                            with self._span("server.parse"):
+                                pkt = W.from_bytes(req.payload)
+                            with self._span("server.stage"):
                                 self._stage(slot, pkt)
-                            except W.WireError:
-                                # the frame leaves the pool; the others
-                                # stay admitted for the next step
-                                self.pool.release(slot)
-                                self.n_rejected += 1
-                                self.rejected.append(req.client_id)
-                                raise
-                st = self._staging
-                # one transfer of the whole pool; a copy, so the staging
+                        except W.WireError:
+                            # the frame leaves the pool; the others stay
+                            # admitted for the next step
+                            self.pool.release(slot)
+                            self.n_rejected += 1
+                            self.rejected.append(req.client_id)
+                            raise
+                        kinds[slot] = pkt.kind
+                # one transfer of each kind's pool; a copy, so the staging
                 # can be rewritten while a device array of it lives
-                with self._span("server.upload") as upload_span:
-                    boundary = jax.device_put((st.codes, st.scales),
-                                              may_alias=False)
-                    if obs.enabled:
-                        upload_span.args.update(frames=len(active),
-                                                bytes=st.nbytes)
-                if obs.enabled:
-                    obs.metrics.counter("runtime.frames_staged").inc(
-                        len(active))
-            # one program for the whole pool (shape is static:
+                boundary = {}
+                for kind in dict.fromkeys(kinds.values()):
+                    st = self._staging[kind]
+                    with self._span("server.upload") as upload_span:
+                        boundary[kind] = jax.device_put(
+                            (st.codes, st.scales), may_alias=False)
+                        if obs.enabled:
+                            upload_span.args.update(
+                                frames=sum(k == kind
+                                           for k in kinds.values()),
+                                bytes=st.nbytes)
+            # one program per kind for the whole pool (shape is static:
             # n_slots * client_batch): decode prologue + the tail
             with self._span("server.tail"):
-                logits = self.part.served_tail(st.kind)(boundary)
+                logits = {k: self.part.served_tail(k)(bd)
+                          for k, bd in boundary.items()}
             with self._span("server.fetch"):
-                host = np.asarray(logits)
-        host = host.reshape((len(self.pool), self.client_batch)
-                            + host.shape[1:])
+                host = {k: np.asarray(v) for k, v in logits.items()}
         out = {}
+        b = self.client_batch
         for slot, req in active:
-            out[req.client_id] = host[slot]
+            out[req.client_id] = host[kinds[slot]][slot * b:(slot + 1) * b]
             self.pool.release(slot)
         self.n_batches += 1
         self.n_served += len(active)

@@ -217,7 +217,7 @@ class Partition:
             cur = seg(cur)
         return cur
 
-    def served_tail(self, kind: str, *, backend: Optional[str] = None):
+    def served_tail(self, kind: str):
         """The tail server's program for ``kind`` payloads:
         ``(data, scales) -> logits``, the hop-0 decode (the fused
         segments' prologue) then everything after the first cut.
@@ -227,19 +227,18 @@ class Partition:
         stage; ``data`` may stack many requests' payloads along its
         leading axis (``scales`` their rows in the same order), so one
         call decodes and serves a whole pool.  The boundary is donated
-        where it can be; cached per ``(kind, backend)``.  A lambda like
-        the stage jits, so its device program is named ``jit__lambda``.
+        where it can be; cached per kind.  A lambda like the stage jits,
+        so its device program is named ``jit__lambda``.
         """
-        key = (kind, backend)
-        if key not in self._served:
+        if kind not in self._served:
             m, p, ae = self.model, self.params, self.ae
             a = self.splits[0] + 1
-            self._served[key] = jax.jit(
+            self._served[kind] = jax.jit(
                 lambda bd: m.apply_range(
-                    p, _decode_pinned(kind, *bd, ae, backend), a,
+                    p, _decode_pinned(kind, *bd, ae, None), a,
                     len(m.layers)),
                 donate_argnums=_donate())
-        return self._served[key]
+        return self._served[kind]
 
     # ------------------------------------------------------------ shapes ----
     def boundary_shape(self, batch: int = 1, hop: int = 0) -> tuple:

@@ -1,8 +1,9 @@
 """``TailServer.step`` stages every frame on the host, uploads the pool
 once and runs one program (``Partition.served_tail``: decode prologue +
 tail).  Each served request must equal the per-request eager path
-``part.tail(W.decode_activation(W.from_bytes(buf), ae))``, and a frame
-whose layout differs from the pool's is refused with a ``WireError``."""
+``part.tail(W.decode_activation(W.from_bytes(buf), ae))``, frames of
+several kinds may share a step, and a frame the partition cannot serve
+is refused with a ``WireError``."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -137,12 +138,75 @@ def test_batch_one_frame_into_a_batch_two_slot_is_refused(parts, toy_data):
 
 
 def test_frame_of_another_kind_is_refused(parts, toy_data):
-    part = parts["int8"]
+    """A kind the partition cannot decode: ae8 without a bottleneck AE."""
+    xs, _ = toy_data
+    server = TailServer(parts["int8"], n_slots=N_SLOTS)
+    msg = _refused(server, _frame(parts["int8"], xs[:1], "int8"),
+                   _frame(parts["ae8"], xs[1:2], "ae8"))
+    assert "no bottleneck AE" in msg
+
+
+def _serve_and_check(server, part, frames, first_cid=0):
+    for i, buf in enumerate(frames):
+        server.submit(first_cid + i, buf)
+    out = server.step()
+    assert sorted(out) == list(range(first_cid, first_cid + len(frames)))
+    for i, buf in enumerate(frames):
+        np.testing.assert_allclose(out[first_cid + i], _eager(part, buf),
+                                   atol=1e-5)
+
+
+def test_frames_of_every_kind_share_one_step(parts, toy_data):
+    """The codec downgrade ladder mixes kinds: one step serves them all."""
+    part = parts["ae8"]
+    xs, _ = toy_data
+    frames = [_frame(part, xs[i:i + 1], k)
+              for i, k in enumerate(["ae8", "int8", "f32"])]
+    server = TailServer(part, n_slots=N_SLOTS)
+    _serve_and_check(server, part, frames)
+    assert server.n_batches == 1 and server.n_rejected == 0
+
+
+def test_kind_switches_between_steps(parts, toy_data):
+    part = parts["ae8"]
     xs, _ = toy_data
     server = TailServer(part, n_slots=N_SLOTS)
-    msg = _refused(server, _frame(part, xs[:1], "f32"),
-                   _frame(part, xs[1:2], "int8"))
-    assert "f32" in msg and "int8" in msg
+    for step, kind in enumerate(["int8", "f32", "ae8", "int8"]):
+        frames = [_frame(part, xs[i:i + 1], kind) for i in range(2)]
+        _serve_and_check(server, part, frames, first_cid=2 * step)
+    assert server.n_batches == 4
+
+
+def test_refused_first_frame_fixes_no_layout(vgg_small, parts, toy_data):
+    """A stray frame from another cut, staged first, is refused and leaves
+    nothing behind: frames of every kind are served after it."""
+    model, params = vgg_small
+    xs, _ = toy_data
+    part = parts["ae8"]
+    other = make_partition(model, params, model.cut_points()[1])
+    server = TailServer(part, n_slots=N_SLOTS)
+    server.submit(9, _frame(other, xs[:1], "f32"))
+    good = [_frame(part, xs[i:i + 1], k)
+            for i, k in enumerate(["int8", "ae8"])]
+    for i, buf in enumerate(good):
+        server.submit(i, buf)
+    with pytest.raises(W.WireError, match="f32"):
+        server.step()
+    assert server.rejected == [9]
+    out = server.step()
+    assert sorted(out) == [0, 1]
+    for i, buf in enumerate(good):
+        np.testing.assert_allclose(out[i], _eager(part, buf), atol=1e-5)
+    _serve_and_check(server, part, [_frame(part, xs[2:3], "f32")],
+                     first_cid=2)
+
+
+def test_unparsable_frame_leaves_the_pool(parts, toy_data):
+    xs, _ = toy_data
+    part = parts["int8"]
+    server = TailServer(part, n_slots=N_SLOTS)
+    good = _frame(part, xs[:1], "int8")
+    _refused(server, good, good[:len(good) // 2])
 
 
 def test_frame_from_another_cut_is_refused(vgg_small, parts, toy_data):

@@ -119,14 +119,6 @@ def test_inputs_end_with_one_upload_of_the_pool(served):
                                "bytes": pool_bytes}
 
 
-def test_frames_staged_counts_every_admitted_frame(served):
-    _, _, rec, _, server, _ = served
-    staged = rec.metrics.get("runtime.frames_staged")
-    assert staged.value == len(CLIENTS) == server.n_served
-    assert len([s for s in rec.tracer.spans
-                if s.name == "server.stage"]) == len(CLIENTS)
-
-
 def test_queue_wait_and_depth(served):
     _, _, rec, _, _, _ = served
     wait = rec.metrics.get("runtime.queue_wait_s")
