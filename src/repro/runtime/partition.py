@@ -13,6 +13,12 @@ The historical 1-cut head/tail vocabulary is preserved exactly:
 ``head`` is stage 0 (layers ``[0, splits[0]]``) and ``tail`` is
 everything after the first cut, so ``tail(head(x)) == apply(x)`` for any
 number of cuts.
+
+Every program takes its own layers' parameters (and the AEs of the hops
+it encodes or decodes) as jit arguments, never as compiled-in constants:
+a full-width VGG16 tail compiles in seconds and fits the persistent
+compile cache.  The callables bind those weights themselves, held on the
+device as the matmuls consume them (:func:`device_weights`).
 """
 from __future__ import annotations
 
@@ -20,11 +26,44 @@ from dataclasses import dataclass, field
 from typing import Optional
 
 import jax
+import jax.numpy as jnp
 
 from repro.core import bottleneck as B
 from repro.core.split import validate_cuts
 from repro.models.layered import LayeredModel
 from repro.runtime import wire as W
+
+# ``jax_default_matmul_precision`` values at which a TPU multiplies f32
+# operands in one bf16 pass, rounding each operand to bf16 first
+_ONE_BF16_PASS = (None, "default", "bfloat16", "BF16_BF16_F32")
+
+
+def _rounds_to_bf16(platform: str, precision) -> bool:
+    return platform == "tpu" and precision in _ONE_BF16_PASS
+
+
+def device_weights(params: list, platform: str, precision) -> list:
+    """The layer parameters as a matmul at ``precision`` (the value of
+    ``jax_default_matmul_precision``) on ``platform`` consumes them.
+
+    A TPU at one-pass bf16 precision rounds every f32 operand of a conv
+    or dense matmul to bf16.  There each layer's f32 kernel (``"w"``) is
+    returned rounded to bf16: a program reads it at two bytes, and casting
+    it back to f32 inside the program is exact, so the MXU sees the
+    operands it would have rounded itself.  Biases stay f32.  Where the
+    precision does not round (the CPU, or ``"highest"``), ``params``
+    comes back as it is."""
+    if not _rounds_to_bf16(platform, precision):
+        return params
+    return _bf16_kernels(params)
+
+
+@jax.jit
+def _bf16_kernels(params: list) -> list:
+    return [{k: v.astype(jnp.bfloat16)
+             if k == "w" and v.dtype == jnp.float32 else v
+             for k, v in p.items()} if isinstance(p, dict) else p
+            for p in params]
 
 
 def _is_single_ae(ae: dict) -> bool:
@@ -32,11 +71,12 @@ def _is_single_ae(ae: dict) -> bool:
     return "enc" in ae and "dec" in ae
 
 
-def _donate() -> tuple:
-    """Donate a program's boundary input where buffers can alias.
-    Donation is a no-op on hosts without buffer aliasing (CPU XLA warns
-    and ignores it), so it is only requested where it can land."""
-    return (0,) if jax.devices()[0].platform != "cpu" else ()
+def _donate(argnum: int) -> tuple:
+    """Donate a program's boundary input (argument ``argnum``, after
+    the weights) where buffers can alias.  Donation is a no-op on hosts
+    without buffer aliasing (CPU XLA warns and ignores it), so it is only
+    requested where it can land."""
+    return (argnum,) if jax.devices()[0].platform != "cpu" else ()
 
 
 def _decode_pinned(kind: str, data, scales, ae: Optional[dict],
@@ -47,6 +87,25 @@ def _decode_pinned(kind: str, data, scales, ae: Optional[dict],
     (``wire._decode_jit`` compiles the same subgraph) to the bit."""
     return jax.lax.optimization_barrier(
         W.decode_arrays(kind, data, scales, ae, backend=backend))
+
+
+class _Bound:
+    """One of a partition's jitted programs with its weights bound:
+    ``prog(*inputs)`` is ``fn(weights[a:b], aes, *inputs)``, the weights
+    as the precision in force at the call consumes them."""
+
+    __slots__ = ("fn", "part", "a", "b", "aes")
+
+    def __init__(self, fn, part: "Partition", a: int, b: int, aes: tuple):
+        self.fn, self.part, self.a, self.b, self.aes = fn, part, a, b, aes
+
+    def __call__(self, *inputs):
+        return self.fn(self.part._weights()[self.a:self.b], self.aes, *inputs)
+
+    def lower(self, *inputs):
+        """The program lowered for ``inputs`` (its weights as arguments)."""
+        return self.fn.lower(self.part._weights()[self.a:self.b], self.aes,
+                             *inputs)
 
 
 @dataclass
@@ -71,6 +130,7 @@ class Partition:
     _tail: object = field(default=None, repr=False)
     _fused: dict = field(default_factory=dict, repr=False)
     _served: dict = field(default_factory=dict, repr=False)
+    _dev: dict = field(default_factory=dict, repr=False)
 
     def __post_init__(self):
         self.splits = validate_cuts(self.model, self.split_layer)
@@ -82,14 +142,49 @@ class Partition:
         else:
             self.ae_map = dict(self.ae)
             self.ae = self.ae_map.get(self.splits[0])
-        m, p = self.model, self.params
-        bounds = (0,) + tuple(c + 1 for c in self.splits) + (len(m.layers),)
-        self._stages = [
-            jax.jit(lambda x, a=a, b=b: m.apply_range(p, x, a, b))
-            for a, b in zip(bounds, bounds[1:])]
+        self._platform = jax.devices()[0].platform
+        self._dtypes = jax.tree.map(jnp.result_type, self.params)
+        bounds = self._bounds()
+        self._stages = [self._program(a, b)
+                        for a, b in zip(bounds, bounds[1:])]
         self._tail = (self._stages[1] if len(self.splits) == 1 else
-                      jax.jit(lambda f: m.apply_range(p, f, self.splits[0] + 1,
-                                                      len(m.layers))))
+                      self._program(bounds[1], bounds[-1]))
+
+    # ----------------------------------------------------------- weights ----
+    def _bounds(self) -> tuple:
+        return ((0,) + tuple(c + 1 for c in self.splits)
+                + (len(self.model.layers),))
+
+    def _weights(self) -> list:
+        """The parameters as this call's matmuls consume them
+        (:func:`device_weights`), put on the device once per rule."""
+        prec = jax.config.jax_default_matmul_precision
+        key = _rounds_to_bf16(self._platform, prec)
+        w = self._dev.get(key)
+        if w is None:
+            w = self._dev[key] = jax.device_put(
+                device_weights(self.params, self._platform, prec))
+        return w
+
+    def _program(self, a: int, b: int, aes: tuple = (), *, decode=None,
+                 encode=None) -> _Bound:
+        """Layers ``[a, b)`` as one jitted program, bound to their weights
+        and to ``aes``: ``decode(boundary, aes)`` as its prologue, when
+        given (the boundary is then donated where it can be), and
+        ``encode(f, aes)`` as its epilogue.  The jitted function is a
+        lambda, so its device program is named ``jit__lambda``."""
+        layers, dtypes = self.model.layers[a:b], self._dtypes[a:b]
+
+        def run(w, aes, x):
+            if decode is not None:
+                x = decode(x, aes)
+            for l, p, dt in zip(layers, w, dtypes):
+                # the device copy back in the parameters' dtypes (exact)
+                x = l.apply(jax.tree.map(lambda v, t: v.astype(t), p, dt), x)
+            return x if encode is None else encode(x, aes)
+        fn = jax.jit(lambda w, aes, x: run(w, aes, x),
+                     donate_argnums=_donate(2) if decode else ())
+        return _Bound(fn, self, a, b, aes)
 
     # ------------------------------------------------------------ stages ----
     @property
@@ -165,11 +260,9 @@ class Partition:
 
     def _build_fused(self, quantize: bool, backend: Optional[str],
                      shard_fn=None) -> list:
-        m, p = self.model, self.params
-        bounds = (0,) + tuple(c + 1 for c in self.splits) + (len(m.layers),)
+        bounds = self._bounds()
         aes = [self.ae_map.get(c) for c in self.splits]
         kinds = self.wire_kinds(quantize)
-        donate = _donate()
 
         # The barriers pin the codec subgraphs (see _decode_pinned): the
         # payload stays bit-identical to the eager byte path's.
@@ -181,29 +274,21 @@ class Partition:
                 scales = shard_fn(scales, "boundary_scales")
             return data, scales
 
-        def enc(f, k):
+        def enc(f, ae):                  # the hop after the stage: ae[-1]
             return pin(*W.encode_arrays(jax.lax.optimization_barrier(f),
-                                        aes[k], quantize=quantize,
+                                        ae[-1], quantize=quantize,
                                         backend=backend))
 
-        def dec(boundary, k):
-            return _decode_pinned(kinds[k], *pin(*boundary), aes[k], backend)
+        def dec(boundary, ae, k):        # hop k, before the stage: ae[0]
+            return _decode_pinned(kinds[k], *pin(*boundary), ae[0], backend)
 
         n = len(self.splits)
-        segs = [jax.jit(lambda x, b=bounds[1]:
-                        enc(m.apply_range(p, x, 0, b), 0))]
+        segs = [self._program(0, bounds[1], (aes[0],), encode=enc)]
         for k in range(1, n + 1):
-            a, b = bounds[k], bounds[k + 1]
-            if k < n:
-                segs.append(jax.jit(
-                    lambda bd, a=a, b=b, k=k:
-                        enc(m.apply_range(p, dec(bd, k - 1), a, b), k),
-                    donate_argnums=donate))
-            else:
-                segs.append(jax.jit(
-                    lambda bd, a=a, b=b, k=k:
-                        m.apply_range(p, dec(bd, k - 1), a, b),
-                    donate_argnums=donate))
+            segs.append(self._program(
+                bounds[k], bounds[k + 1], tuple(aes[k - 1:k + 1]),
+                decode=lambda bd, ae, k=k: dec(bd, ae, k - 1),
+                encode=enc if k < n else None))
         return segs
 
     def fused_forward(self, x: jax.Array, *, quantize: bool = True,
@@ -231,13 +316,9 @@ class Partition:
         so its device program is named ``jit__lambda``.
         """
         if kind not in self._served:
-            m, p, ae = self.model, self.params, self.ae
-            a = self.splits[0] + 1
-            self._served[kind] = jax.jit(
-                lambda bd: m.apply_range(
-                    p, _decode_pinned(kind, *bd, ae, None), a,
-                    len(m.layers)),
-                donate_argnums=_donate())
+            self._served[kind] = self._program(
+                self.splits[0] + 1, len(self.model.layers), (self.ae,),
+                decode=lambda bd, ae: _decode_pinned(kind, *bd, ae[0], None))
         return self._served[kind]
 
     # ------------------------------------------------------------ shapes ----
@@ -252,7 +333,7 @@ class Partition:
             return (f"{m.name}: head=[0..{self.split_layer}] "
                     f"tail=[{self.split_layer + 1}..{len(m.layers) - 1}]"
                     f"{' +ae' if self.ae is not None else ''}")
-        bounds = (0,) + tuple(c + 1 for c in self.splits) + (len(m.layers),)
+        bounds = self._bounds()
         stages = " | ".join(f"stage{i}=[{a}..{b - 1}]"
                             for i, (a, b) in enumerate(zip(bounds, bounds[1:])))
         aes = sorted(self.ae_map)

@@ -19,8 +19,9 @@ The codec exists at two altitudes:
 * **array layer** (:func:`encode_arrays` / :func:`decode_arrays`) —
   pure-JAX, jittable transforms between the boundary activation and the
   device-resident wire tensors ``(data, scales)``.  This is what
-  ``Partition.fused_segments`` closes over so encode fuses into the tail
-  of a stage and decode into the head of the next.
+  ``Partition.fused_segments`` composes with the stages (the AE passed
+  as an argument) so encode fuses into the tail of a stage and decode
+  into the head of the next.
 * **byte layer** (:func:`frame_arrays` / :func:`to_bytes` /
   :func:`from_bytes`) — the self-describing framing.  ``frame_arrays``
   is the zero-copy path: the header is written *around* the kernel's

@@ -149,13 +149,13 @@ def test_fused_wire_path_equals_eager(b, rows, c, kind, seed):
     pkt = W.encode_activation(f, ae, quantize=quantize)
     buf_eager = W.to_bytes(pkt)
     out_eager = np.asarray(W.decode_activation(W.from_bytes(buf_eager), ae))
-    enc = jax.jit(lambda v: W.encode_arrays(v, ae, quantize=quantize))
-    data, scales = enc(f)
+    enc = jax.jit(lambda v, a: W.encode_arrays(v, a, quantize=quantize))
+    data, scales = enc(f, ae)
     buf_fused = W.frame_arrays(kind, data, scales)
     assert buf_fused == buf_eager
     d2, s2 = W.parse_arrays(buf_fused)
-    dec = jax.jit(lambda d, s: W.decode_arrays(kind, d, s, ae))
-    out_fused = np.asarray(dec(d2, s2))
+    dec = jax.jit(lambda d, s, a: W.decode_arrays(kind, d, s, a))
+    out_fused = np.asarray(dec(d2, s2, ae))
     np.testing.assert_array_equal(out_fused, out_eager)
 
 

@@ -21,6 +21,7 @@ from repro.kernels.bottleneck_compress import bottleneck_compress_any
 from repro.kernels.bottleneck_decompress import bottleneck_decompress_any
 from repro.models.vgg import vgg16
 from repro.runtime import wire as W
+from repro.runtime.partition import device_weights, make_partition
 
 BATCH = 8
 CUT_SHAPES = {
@@ -117,3 +118,33 @@ def test_vgg16_tail_stage_compiles_for_v5e(one_chip):
         _spec(one_chip, (_rows(boundary), 1))).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis() is not None
+
+
+def test_vgg16_served_tail_reads_bf16_kernels_on_v5e(one_chip):
+    """``Partition.served_tail`` after block5_pool at full width, its
+    weights as a TPU at the default precision holds them: each kernel is
+    read as its bf16 argument, and the program makes no f32 copy of it."""
+    model = vgg16()
+    cut = 30                                      # block5_pool: 7x7x512
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+    params = jax.eval_shape(model.init, key)
+    boundary = model.activation_shapes(params, BATCH)[cut]
+    ae = jax.eval_shape(lambda k: B.init_bottleneck(k, boundary[1:], 0.5),
+                        key)
+    prog = make_partition(model, params, cut, ae).served_tail("ae8")
+    tail = params[prog.a:prog.b]
+    weights = jax.eval_shape(lambda p: device_weights(p, "tpu", None), tail)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype), tree)
+
+    l = ae["enc"]["w"].shape[1]
+    text = prog.fn.lower(
+        on_chip(weights), on_chip((ae,)),
+        (_spec(one_chip, boundary[:-1] + (l,), jnp.int8),
+         _spec(one_chip, (_rows(boundary), 1)))).compile().as_text()
+    kernels = [",".join(map(str, p["w"].shape)) for p in tail if "w" in p]
+    assert len(kernels) == 3
+    for dims in kernels:
+        assert f"bf16[{dims}]" in text
+        assert f"f32[{dims}]" not in text
