@@ -10,14 +10,6 @@ emitted.  The per-split prediction error is the repo's ground-truth check
 that the simulators mean something (paper claim iii), and the JSON
 artifact is the CI regression gate's input.
 
-Each split is additionally executed on the **fused-boundary** path
-(``SplitRuntime(fused=True)``: codec jitted into the stages, only
-framing/parse on the host) and the per-boundary overhead — the host-side
-encode + decode work around one wire hop — is reported fused vs eager.
-Two hard floors are asserted in-bench (back-to-back measurements, so
-host load cancels): the fused wire payload is byte-identical to the
-eager one, and the fused path cuts per-boundary overhead by >= 20%.
-
   PYTHONPATH=src python -m benchmarks.bench_runtime [--quick] [--out PATH]
 """
 from __future__ import annotations
@@ -49,22 +41,6 @@ def _model(quick: bool):
     return trained_vgg()
 
 
-def _assert_payload_bit_identical(rt_eager, rt_fused, x, split):
-    """The fused path must put the exact same bytes on the wire."""
-    import jax.numpy as jnp
-    from repro.runtime import wire as W
-    xj = jnp.asarray(x)
-    part_e, part_f = rt_eager.part, rt_fused.part
-    f0 = part_e.stage(0)(xj)
-    buf_e = W.to_bytes(W.encode_activation(f0, part_e.ae_map.get(split)))
-    out0 = part_f.fused_segments()[0](xj)
-    buf_f = W.frame_arrays(part_f.wire_kinds()[0], out0[0], out0[1])
-    if buf_f != buf_e:
-        raise AssertionError(
-            f"split {split}: fused wire payload not bit-identical to eager "
-            f"({len(buf_f)} vs {len(buf_e)} B)")
-
-
 def _pick_splits(model, k: int = 4) -> list:
     cuts = model.cut_points()
     idx = np.linspace(0, len(cuts) - 1, min(k, len(cuts))).astype(int)
@@ -93,13 +69,6 @@ def run(fast: bool = False, out_path: str = None) -> list:
                           include_rc=False, include_lc=False)
         rt = SplitRuntime(model, params, split, channel=ch, quantize=True)
         res = rt.infer(x, iters=iters)
-        rt_f = SplitRuntime(model, params, split, channel=ch, quantize=True,
-                            fused=True)
-        res_f = rt_f.infer(x, iters=iters)
-        if not np.array_equal(res.logits, res_f.logits):
-            raise AssertionError(
-                f"split {split}: fused logits diverged from eager")
-        _assert_payload_bit_identical(rt, rt_f, x, split)
         sc = Scenario("SC", SplitPlan(split))
         flow_m = measure_flow(sc, netcfg, model, params, input_bytes,
                               cost=table, batch=batch)
@@ -119,23 +88,11 @@ def run(fast: bool = False, out_path: str = None) -> list:
             "head_ms": res.head_s * 1e3,
             "tail_ms": res.tail_s * 1e3,
             "transfer_ms": res.transfer_s * 1e3,
-            # host-side boundary work around the wire hop: eager = codec
-            # dispatch + serialise/parse + codec compute; fused = framing
-            # + parse only (the codec compute runs inside the stage jit)
-            "per_boundary_overhead_s": {
-                "eager": res.encode_s + res.decode_s,
-                "fused": res_f.encode_s + res_f.decode_s,
-            },
-            "boundary_cut_pct": (1.0 - (res_f.encode_s + res_f.decode_s)
-                                 / (res.encode_s + res.decode_s)) * 100,
-            "exec_fused_ms": res_f.total_s * 1e3,
+            # host-side work around the wire hop: codec dispatch +
+            # serialise/parse + codec compute
+            "boundary_overhead_ms": (res.encode_s + res.decode_s) * 1e3,
         })
 
-    cut_pct = float(np.mean([r["boundary_cut_pct"] for r in rows]))
-    if cut_pct < 20.0:
-        raise AssertionError(
-            f"fused boundary overhead cut {cut_pct:.1f}% < the 20% floor "
-            f"(per split: {[round(r['boundary_cut_pct'], 1) for r in rows]})")
     report = {
         "quick": fast,
         "model": model.name,
@@ -146,16 +103,6 @@ def run(fast: bool = False, out_path: str = None) -> list:
                                                 for r in rows])),
         "mean_err_analytic_pct": float(np.mean([r["err_analytic_pct"]
                                                 for r in rows])),
-        "boundary": {
-            # mean over splits; the >=20% floor and payload bit-identity
-            # are asserted above, so these are records, not gates
-            "overhead_cut_pct": cut_pct,
-            "fused_bit_identical": 1.0,
-            "eager_overhead_ms": float(np.mean(
-                [r["per_boundary_overhead_s"]["eager"] for r in rows])) * 1e3,
-            "fused_overhead_ms": float(np.mean(
-                [r["per_boundary_overhead_s"]["fused"] for r in rows])) * 1e3,
-        },
     }
     out_path = out_path or os.path.join(RESULTS_DIR, "runtime",
                                         "bench_runtime.json")
@@ -173,8 +120,6 @@ def run(fast: bool = False, out_path: str = None) -> list:
                     round(r["err_analytic_pct"], 1)))
     out.append(("runtime.max_err_measured_pct", 0.0,
                 round(report["max_err_measured_pct"], 1)))
-    out.append(("runtime.boundary.overhead_cut_pct", 0.0,
-                round(report["boundary"]["overhead_cut_pct"], 1)))
     return out
 
 

@@ -11,8 +11,8 @@ goes through the entry points a user calls:
 
 and every result is checked against a reference: the f32-wire split
 against a plain float32 unsplit forward, the codec kernels against
-``kernels/ref.py``, the served logits against the single-client runtime,
-and the fused-boundary runtime against the eager one.
+``kernels/ref.py``, and the served logits against the single-client
+runtime.
 
 Four chips (``--chips 4``): only the path where the wire crosses devices,
 ``core.split.multipod_split_step`` on a (pod=2, data=2) mesh with
@@ -80,11 +80,6 @@ TOL_KERNEL_DECODE_LOGITS = 1e-3
 # decode; the tail runs at batch n_slots in the server and 1 in the
 # runtime, so only the f32 summation order may differ.
 TOL_SERVED_VS_SINGLE = 1e-4
-# Fused vs eager ae8 runtime: fusing the head with the encoder may change
-# the head's last bits, so a code may move by one step (1/127 of its row's
-# amax, 0.8%); were every code to move, the logits would move by about
-# that much.
-TOL_FUSED_VS_EAGER = 2e-2
 # Multi-pod pipeline vs the single program, both at "highest": the same f32
 # arithmetic in another summation order.
 TOL_PIPELINE_F32 = 1e-4
@@ -316,21 +311,6 @@ def run_single_chip(model, clock: CompileClock, checks: Checks, *,
         f"clients 0 and 1: {rel_err(served[1], served[0])!r}")
     checks("served vs single-client logits (worst client)", worst,
            TOL_SERVED_VS_SINGLE, "same frames; only the tail batch differs")
-
-    # --- fused boundary ---------------------------------------------------
-    with phase(clock, "fused"):
-        rtf = study.deploy(candidate, fused=True)
-        outf = rtf.infer(x, iters=1).logits
-        data, scales = rtf.part.fused_segments()[0](x)
-        fused_bytes = W.frame_arrays("ae8", data, scales)
-        eager_bytes = W.to_bytes(W.encode_activation(f, ae))
-    same = fused_bytes == eager_bytes
-    moved = int(np.sum(np.asarray(data).reshape(-1)
-                       != W.from_bytes(eager_bytes).data.reshape(-1)))
-    log(f"fused bytes equal eager bytes: {same} (codes that differ: {moved} "
-        f"of {q.size}; not checked, ROADMAP C1(ii))")
-    checks("fused vs eager ae8 logits", rel_err(outf, res.logits),
-           TOL_FUSED_VS_EAGER, "a code may move one step when fused")
 
 
 # ----------------------------------------------------------- four chips ----

@@ -373,25 +373,21 @@ class Study:
         return self
 
     def calibrate(self, *, splits: Optional[Sequence[int]] = None,
-                  iters: int = 3, quantize: bool = True,
-                  fused: bool = False) -> "Study":
+                  iters: int = 3, quantize: bool = True) -> "Study":
         """Optional stage: execute the real head/tail stages and wire codec
         on this host and keep the measured
         :class:`~repro.runtime.calibrate.CalibrationTable`.  Every later
         ``simulate`` (single-link *and* fleet) prices flows from it,
-        falling back to the analytic model for uncovered cells.
-        ``fused=True`` measures the fused-boundary runtime (codec jitted
-        into the stages) and quotes those costs to the planners."""
+        falling back to the analytic model for uncovered cells."""
         from repro.runtime.calibrate import calibrate as _calibrate
         splits = [c.split_layer for c in self.split_candidates()] \
             if splits is None else list(splits)
         with self._obs.tracer.span("study.calibrate", tid="study",
                                    cat="study") as sp:
-            sp.args.update(n_splits=len(splits), iters=iters, fused=fused)
+            sp.args.update(n_splits=len(splits), iters=iters)
             self._calibration = _calibrate(self.model, self.params, splits,
                                            ae_map=self._ae_map, x=self._x,
-                                           iters=iters, quantize=quantize,
-                                           fused=fused)
+                                           iters=iters, quantize=quantize)
         self._mode = None
         return self
 
@@ -767,8 +763,8 @@ class Study:
         return cand, self.scenario.protocol
 
     def deploy(self, candidate=None, *, device=None, serve: bool = False,
-               n_slots: int = 4, quantize: bool = True, backend=None,
-               fused: bool = False, faults=None, recovery=None):
+               n_slots: int = 4, quantize: bool = True, faults=None,
+               recovery=None):
         """Stage 5: a ready runtime for the chosen cut (or cut list).
 
         Returns a :class:`~repro.runtime.engine.SplitRuntime` executing
@@ -809,10 +805,9 @@ class Study:
         if isinstance(hops, str):            # protocol over the study link
             return SplitRuntime(self.model, self.params, splits, ae=ae,
                                 channel=self.scenario.channel, protocol=hops,
-                                quantize=quantize, backend=backend,
-                                fused=fused, obs=self._recorder,
+                                quantize=quantize, obs=self._recorder,
                                 faults=faults, recovery=recovery)
         return SplitRuntime(self.model, self.params, splits, ae=ae,
-                            channel=hops, quantize=quantize, backend=backend,
-                            fused=fused, obs=self._recorder,
-                            faults=faults, recovery=recovery)
+                            channel=hops, quantize=quantize,
+                            obs=self._recorder, faults=faults,
+                            recovery=recovery)

@@ -43,23 +43,14 @@ class CalEntry:
     wire_bytes: int                  # actual serialized payload size
     encode_s: float = 0.0            # edge-side codec
     decode_s: float = 0.0            # server-side codec
-    fused_edge_s: float = 0.0        # fused seg0 + framing (calibrate(fused=True))
-    fused_server_s: float = 0.0      # parse + fused decode/tail segment
-    use_fused: bool = False          # quote fused costs from edge_s/server_s
 
     @property
     def edge_s(self) -> float:
-        """Edge wall clock as the planner prices it: the fused-boundary
-        measurement when ``use_fused`` (one jitted leg + framing), else
-        head compute + eager codec."""
-        if self.use_fused:
-            return self.fused_edge_s
+        """Edge wall clock as the planner prices it: head compute + codec."""
         return self.head_s + self.encode_s
 
     @property
     def server_s(self) -> float:
-        if self.use_fused:
-            return self.fused_server_s
         return self.decode_s + self.tail_s
 
 
@@ -148,21 +139,13 @@ def calibrate(model, params, splits: Sequence[int], *,
               ae_map: Optional[dict] = None, batch: int = 1,
               x: Optional[np.ndarray] = None, iters: int = 3,
               quantize: bool = True, include_rc: bool = True,
-              include_lc: bool = True, fused: bool = False,
-              seed: int = 0) -> CalibrationTable:
+              include_lc: bool = True, seed: int = 0) -> CalibrationTable:
     """Measure per-stage compute and wire payload over a split grid.
 
     Runs on this host (HIL: the measured hardware stands in for both edge
     and server — scale or re-measure per platform for heterogeneous
     deployments).  ``ae_map``: split -> trained bottleneck AE; splits
     without an entry ship the raw int8 activation.
-
-    ``fused=True`` additionally measures the fused-boundary execution
-    (``Partition.fused_segments``: codec fused into the stage jit, only
-    framing/parse on the host) and marks the entries ``use_fused``, so
-    ``edge_s``/``server_s`` — and every planner/simulator consuming this
-    table through the CostModel protocol — price the fused runtime.  The
-    eager per-component times are always kept alongside.
 
     ``x`` may be any input pytree the model consumes (a transformer
     layered view takes a batch dict); the calibration batch is its
@@ -178,7 +161,6 @@ def calibrate(model, params, splits: Sequence[int], *,
     batch = int(leaves[0].shape[0])  # the table's batch is x's, always
     table = CalibrationTable(model.name, batch,
                              meta={"iters": iters, "quantize": quantize,
-                                   "fused": fused,
                                    "n_splits": len(splits)})
 
     full_s, _ = timeit_blocked(lambda v: model.apply(params, v), x,
@@ -202,25 +184,6 @@ def calibrate(model, params, splits: Sequence[int], *,
             lambda b: W.decode_activation(W.from_bytes(b), ae), buf,
             iters=iters, warmup=1)
         tail_s, _ = timeit_blocked(part.tail, f_hat, iters=iters)
-        extra = {}
-        if fused:
-            segs = part.fused_segments(quantize=quantize)
-            kind = part.wire_kinds(quantize)[0]
-            seg0_s, out = timeit_blocked(segs[0], x, iters=iters)
-            frame_s, fbuf = timeit_blocked(
-                lambda d, s: W.frame_arrays(kind, d, s), out[0], out[1],
-                iters=iters)
-            # the server leg re-parses per call (the segment donates its
-            # boundary input); parse + decode + tail is one measurement —
-            # exactly the wall clock a fused server spends per request
-            leg_s, _ = timeit_blocked(
-                lambda b: segs[1](W.parse_arrays(b)), fbuf, iters=iters)
-            if len(fbuf) != len(buf):
-                raise AssertionError(
-                    f"fused wire framing diverged from eager at split "
-                    f"{split}: {len(fbuf)} vs {len(buf)} bytes")
-            extra = {"fused_edge_s": seg0_s + frame_s,
-                     "fused_server_s": leg_s, "use_fused": True}
         table.put("SC", split,
-                  CalEntry(head_s, tail_s, len(buf), enc_s, dec_s, **extra))
+                  CalEntry(head_s, tail_s, len(buf), enc_s, dec_s))
     return table

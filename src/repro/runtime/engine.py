@@ -149,27 +149,23 @@ class SplitRuntime:
     ('f32' for the exactness oracle).  ``ae`` may be one AE dict (first
     cut) or a ``{cut: ae}`` map.
 
-    ``fused=True`` switches the execution to the fused-boundary path
-    (``Partition.fused_segments``): each leg is ONE jitted callable with
-    the wire encode fused as the stage epilogue and the decode as the
-    next stage's prologue, so the only host-side work per hop is the
-    zero-copy byte framing and the parse.  The payload bytes are
-    bit-identical to the eager path — ``fused`` changes where time goes
-    (hop ``encode_s``/``decode_s`` shrink to framing/parse; the codec
-    compute moves into ``stage_s``), never the numbers on the wire.
+    Each stage runs as its own jitted program and each hop through the
+    jitted wire codec (``wire.encode_activation`` -> bytes ->
+    ``wire.decode_activation``), so hop ``encode_s``/``decode_s`` hold
+    the codec and ``stage_s`` the layers alone.  The many-client server
+    side, where the decode is the prologue of one batched tail program,
+    is :class:`TailServer`.
     """
 
     def __init__(self, model, params, split_layer, *,
                  ae: Optional[dict] = None,
                  channel=None, protocol: str = "tcp",
-                 quantize: bool = True, backend: Optional[str] = None,
-                 fused: bool = False, obs=None,
+                 quantize: bool = True, obs=None,
                  faults: Optional[FaultPlan] = None,
                  recovery: Optional[RecoveryPolicy] = None):
         self.part: Partition = make_partition(model, params, split_layer, ae)
         self.channel, self.protocol = channel, protocol
-        self.quantize, self.backend = quantize, backend
-        self.fused = fused
+        self.quantize = quantize
         self.hops = self._resolve_hops(channel, protocol)
         self.obs = NULL if obs is None else obs
         # fault injection + recovery: only consulted when a plan is
@@ -199,8 +195,7 @@ class SplitRuntime:
 
     # ------------------------------------------------------------ stages ----
     def _encode(self, f, ae):
-        return W.encode_activation(f, ae, quantize=self.quantize,
-                                   backend=self.backend)
+        return W.encode_activation(f, ae, quantize=self.quantize)
 
     def _price_hop(self, k: int, nbytes: int, stream: int) -> tuple:
         """netsim-priced transfer of hop k: (transfer_s, transport meta)."""
@@ -211,12 +206,6 @@ class SplitRuntime:
         return tr.duration_s, {"n_packets": tr.n_packets,
                                "n_transmissions": tr.n_transmissions,
                                "loss_fraction": tr.loss_fraction}
-
-    @staticmethod
-    def _parse(buf: bytes) -> tuple:
-        """Wire bytes -> boundary pytree, rebuilt per call: the fused
-        segments donate their boundary input, so a parse is single-use."""
-        return W.parse_arrays(buf)
 
     def infer(self, x, *, iters: int = 3, stream: int = 0,
               rid: int = 0) -> RuntimeResult:
@@ -229,17 +218,13 @@ class SplitRuntime:
             logits, stage_s, hops, extra = self._run_recovering(
                 x, iters=iters, stream=stream, rid=rid)
             return self._package(logits, stage_s, hops, extra)
-        if self.fused:
-            logits, stage_s, hops = self._run_fused(x, iters=iters,
-                                                    stream=stream)
-        else:
-            logits, stage_s, hops = self._run_eager(x, iters=iters,
-                                                    stream=stream)
+        logits, stage_s, hops = self._run_eager(x, iters=iters,
+                                                stream=stream)
         return self._package(logits, stage_s, hops)
 
     def _run_eager(self, x, *, iters: int, stream: int) -> tuple:
-        """Historical op-by-op path: stage jit, then codec on the host
-        (the exactness + accounting oracle for the fused path)."""
+        """Stage by stage: each stage's jit, then the hop's jitted codec
+        through real wire bytes."""
         cur = jnp.asarray(x)
         stage_s, hops = [], []
         for k in range(self.part.n_stages):
@@ -259,38 +244,6 @@ class SplitRuntime:
                          "decode_s": decode_s, **meta})
         return cur, stage_s, hops
 
-    def _run_fused(self, x, *, iters: int, stream: int) -> tuple:
-        """Fused-boundary path: one jitted wire-to-wire segment per leg.
-
-        Accounting: the codec compute is inside the segments, so
-        ``stage_s[k]`` absorbs it; hop ``encode_s`` is just the zero-copy
-        framing and ``decode_s`` just the byte parse.  The middle/last
-        legs are timed as ``seg(parse(buf))`` (fresh boundary arrays per
-        call — the segments donate their input) and the parse time is
-        measured separately and subtracted, so the split between
-        ``decode_s`` and ``stage_s`` stays honest.
-        """
-        segs = self.part.fused_segments(quantize=self.quantize,
-                                        backend=self.backend)
-        kinds = self.part.wire_kinds(self.quantize)
-        stage_s, hops = [], []
-        s0, out = timeit_blocked(segs[0], jnp.asarray(x), iters=iters)
-        stage_s.append(s0)
-        for k in range(len(self.part.splits)):
-            encode_s, buf = timeit_blocked(
-                lambda d, s, kk=k: W.frame_arrays(kinds[kk], d, s),
-                out[0], out[1], iters=iters)
-            transfer_s, meta = self._price_hop(k, len(buf), stream)
-            parse_s, _ = timeit_blocked(self._parse, buf, iters=iters)
-            leg_s, out = timeit_blocked(
-                lambda b, kk=k: segs[kk + 1](self._parse(b)),
-                buf, iters=iters)
-            stage_s.append(max(0.0, leg_s - parse_s))
-            hops.append({"cut": self.part.splits[k], "bytes": len(buf),
-                         "encode_s": encode_s, "transfer_s": transfer_s,
-                         "decode_s": parse_s, **meta})
-        return out, stage_s, hops
-
     # ------------------------------------------------------- recovery ----
     def _encode_rung(self, f, ae_k, kind: str) -> bytes:
         """Encode the boundary activation at one degradation rung, as a
@@ -298,11 +251,9 @@ class SplitRuntime:
         lower rungs re-encode locally from the same activation
         (ae8 -> int8 -> f32), so a downgrade never needs a round-trip."""
         if kind == "ae8":
-            pkt = W.encode_activation(f, ae_k, quantize=True,
-                                      backend=self.backend)
+            pkt = W.encode_activation(f, ae_k, quantize=True)
         else:
-            pkt = W.encode_activation(f, None, quantize=(kind == "int8"),
-                                      backend=self.backend)
+            pkt = W.encode_activation(f, None, quantize=(kind == "int8"))
         return W.to_bytes(pkt, checksum=True)
 
     @staticmethod
@@ -445,20 +396,16 @@ class SplitRuntime:
         in the retry/backoff/degradation machinery of
         :class:`~repro.runtime.faults.RecoveryPolicy`.
 
-        Runs eagerly even under ``fused=True`` (recorded as
-        ``meta["recovery"]["exec"]``): codec downgrade re-encodes from
-        the raw boundary activation, which fused segments never expose —
-        and since fused==eager bit-identity is an enforced invariant,
-        outputs and payload bytes are identical either way.  Frames ship
-        as SEI2 (CRC32-checksummed), so corruption is detected, never
-        decoded; zero-fault runs (``faults=None``) never enter here.
+        Codec downgrade re-encodes from the raw boundary activation.
+        Frames ship as SEI2 (CRC32-checksummed), so corruption is
+        detected, never decoded; zero-fault runs (``faults=None``) never
+        enter here.
         """
         plan = self.faults
         counts = {"drop": 0, "corrupt": 0, "straggle": 0, "stage": 0,
                   "blackout": 0}
         rec = {"retries": 0, "timeouts": 0, "backoff_s": 0.0,
-               "downgrades": [], "local_fallback": False, "exec": "eager",
-               "log": []}
+               "downgrades": [], "local_fallback": False, "log": []}
         t = 0.0
         cur = jnp.asarray(x)
         stage_s, hops = [], []
@@ -517,7 +464,7 @@ class SplitRuntime:
             sum(stage_s[1:]),
             sum(h["bytes"] for h in hops),
             {**(dict(hops[0]) if len(hops) == 1 else {"hops": hops}),
-             "fused": self.fused, **(extra_meta or {})},
+             **(extra_meta or {})},
             splits=self.part.splits, stage_s=tuple(stage_s),
             hops=tuple(hops))
         obs = self.obs

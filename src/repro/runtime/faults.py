@@ -12,7 +12,7 @@ This module is the runtime's half of the sim-vs-reality loop for
   stragglers, stage exceptions).  Every decision is a pure function of
   ``(seed, request, hop/stage, attempt)`` — never of wall-clock time or
   execution order — so the same plan replays the identical fault
-  sequence across runs, across ``fused=True/False``, and across hosts.
+  sequence across runs and across hosts.
 * :class:`RecoveryPolicy` — what the runtime *does* about it: per-hop
   timeouts derived from the netsim channel RTO (the same constant
   ``netsim.protocols.simulate_tcp`` arms its retransmission timers

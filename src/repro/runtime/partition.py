@@ -128,7 +128,6 @@ class Partition:
     ae: Optional[dict] = None
     _stages: list = field(default=None, repr=False)
     _tail: object = field(default=None, repr=False)
-    _fused: dict = field(default_factory=dict, repr=False)
     _served: dict = field(default_factory=dict, repr=False)
     _dev: dict = field(default_factory=dict, repr=False)
 
@@ -166,13 +165,13 @@ class Partition:
                 device_weights(self.params, self._platform, prec))
         return w
 
-    def _program(self, a: int, b: int, aes: tuple = (), *, decode=None,
-                 encode=None) -> _Bound:
+    def _program(self, a: int, b: int, aes: tuple = (), *,
+                 decode=None) -> _Bound:
         """Layers ``[a, b)`` as one jitted program, bound to their weights
-        and to ``aes``: ``decode(boundary, aes)`` as its prologue, when
-        given (the boundary is then donated where it can be), and
-        ``encode(f, aes)`` as its epilogue.  The jitted function is a
-        lambda, so its device program is named ``jit__lambda``."""
+        and to ``aes``, with ``decode(boundary, aes)`` as its prologue when
+        given (the boundary is then donated where it can be).  The jitted
+        function is a lambda, so its device program is named
+        ``jit__lambda``."""
         layers, dtypes = self.model.layers[a:b], self._dtypes[a:b]
 
         def run(w, aes, x):
@@ -181,7 +180,7 @@ class Partition:
             for l, p, dt in zip(layers, w, dtypes):
                 # the device copy back in the parameters' dtypes (exact)
                 x = l.apply(jax.tree.map(lambda v, t: v.astype(t), p, dt), x)
-            return x if encode is None else encode(x, aes)
+            return x
         fn = jax.jit(lambda w, aes, x: run(w, aes, x),
                      donate_argnums=_donate(2) if decode else ())
         return _Bound(fn, self, a, b, aes)
@@ -214,106 +213,25 @@ class Partition:
             x = s(x)
         return x
 
-    # ----------------------------------------------------- fused boundary ----
+    # ------------------------------------------------------------ served ----
     def wire_kinds(self, quantize: bool = True) -> tuple:
         """Per-hop payload kind ('f32' | 'int8' | 'ae8') — static given
         the AE map and the quantize flag."""
         return tuple(W.wire_kind(self.ae_map.get(c), quantize)
                      for c in self.splits)
 
-    def fused_segments(self, *, quantize: bool = True,
-                       backend: Optional[str] = None,
-                       shard_fn=None) -> list:
-        """K+1 wire-to-wire jitted callables: the fused-boundary runtime.
-
-        Where :meth:`stage` callables map activation -> activation and
-        leave the codec to the caller (the eager path, one host
-        round-trip per leg), each fused segment runs its layers *and*
-        the boundary codec in ONE jitted program:
-
-        * segment 0: ``x -> (data, scales)`` — stage-0 layers with the
-          hop-0 encode (projection + ReLU + per-row amax + int8 for
-          'ae8') fused as the stage epilogue, so the f32 latent never
-          leaves the device between the last layer and the quantiser;
-        * middle segment k: ``(data, scales) -> (data, scales)`` — hop
-          k-1 decode (dequantise + AE-decoder) as the stage prologue,
-          the stage layers, then the hop-k encode epilogue;
-        * last segment: ``(data, scales) -> logits``.
-
-        Boundary inputs are **donated** (the int8 codes + scales buffers
-        are dead once decoded, so XLA may reuse them) — a segment must
-        therefore be fed freshly parsed arrays on every call.  Segments
-        are cached per ``(quantize, backend)``; byte framing stays
-        outside (``wire.frame_arrays`` writes the header around the
-        kernel output).  ``fused == eager`` to int8 bit-identity is the
-        contract tests enforce (see ``tests/test_fused_boundary.py``).
-
-        ``shard_fn`` (a ``sharding.rules.make_shard_fn`` hook) pins the
-        boundary tensors inside the jitted segments — kinds
-        ``boundary_codes`` / ``boundary_scales``, batch-row sharded so a
-        row's codes and its scale co-locate.
-        """
-        key = (quantize, backend, shard_fn)
-        if key not in self._fused:
-            self._fused[key] = self._build_fused(quantize, backend, shard_fn)
-        return self._fused[key]
-
-    def _build_fused(self, quantize: bool, backend: Optional[str],
-                     shard_fn=None) -> list:
-        bounds = self._bounds()
-        aes = [self.ae_map.get(c) for c in self.splits]
-        kinds = self.wire_kinds(quantize)
-
-        # The barriers pin the codec subgraphs (see _decode_pinned): the
-        # payload stays bit-identical to the eager byte path's.
-        def pin(data, scales):
-            if shard_fn is None:
-                return data, scales
-            data = shard_fn(data, "boundary_codes")
-            if scales is not None:
-                scales = shard_fn(scales, "boundary_scales")
-            return data, scales
-
-        def enc(f, ae):                  # the hop after the stage: ae[-1]
-            return pin(*W.encode_arrays(jax.lax.optimization_barrier(f),
-                                        ae[-1], quantize=quantize,
-                                        backend=backend))
-
-        def dec(boundary, ae, k):        # hop k, before the stage: ae[0]
-            return _decode_pinned(kinds[k], *pin(*boundary), ae[0], backend)
-
-        n = len(self.splits)
-        segs = [self._program(0, bounds[1], (aes[0],), encode=enc)]
-        for k in range(1, n + 1):
-            segs.append(self._program(
-                bounds[k], bounds[k + 1], tuple(aes[k - 1:k + 1]),
-                decode=lambda bd, ae, k=k: dec(bd, ae, k - 1),
-                encode=enc if k < n else None))
-        return segs
-
-    def fused_forward(self, x: jax.Array, *, quantize: bool = True,
-                      backend: Optional[str] = None) -> jax.Array:
-        """Run the whole fused segment chain (no byte framing) — the
-        device-only equivalent of :meth:`forward_stages` on the fused
-        path."""
-        segs = self.fused_segments(quantize=quantize, backend=backend)
-        cur = segs[0](x)
-        for seg in segs[1:]:
-            cur = seg(cur)
-        return cur
-
     def served_tail(self, kind: str):
         """The tail server's program for ``kind`` payloads:
-        ``(data, scales) -> logits``, the hop-0 decode (the fused
-        segments' prologue) then everything after the first cut.
+        ``(data, scales) -> logits``, the hop-0 decode as its prologue
+        then everything after the first cut.
 
-        ``served_tail(kind)(boundary)`` equals ``tail(decode)`` for any
-        number of cuts, where the last fused segment covers only the last
-        stage; ``data`` may stack many requests' payloads along its
-        leading axis (``scales`` their rows in the same order), so one
-        call decodes and serves a whole pool.  The boundary is donated
-        where it can be; cached per kind.  A lambda like the stage jits,
-        so its device program is named ``jit__lambda``.
+        ``served_tail(kind)(boundary)`` equals ``tail`` of the eager
+        decode (``wire.decode_activation``) for any number of cuts;
+        ``data`` may stack many requests' payloads along its leading axis
+        (``scales`` their rows in the same order), so one call decodes
+        and serves a whole pool.  The boundary is donated where it can
+        be; cached per kind.  A lambda like the stage jits, so its device
+        program is named ``jit__lambda``.
         """
         if kind not in self._served:
             self._served[kind] = self._program(

@@ -18,16 +18,14 @@ The codec exists at two altitudes:
 
 * **array layer** (:func:`encode_arrays` / :func:`decode_arrays`) —
   pure-JAX, jittable transforms between the boundary activation and the
-  device-resident wire tensors ``(data, scales)``.  This is what
-  ``Partition.fused_segments`` composes with the stages (the AE passed
-  as an argument) so encode fuses into the tail of a stage and decode
-  into the head of the next.
+  device-resident wire tensors ``(data, scales)``.  The byte layer runs
+  them jitted, and ``Partition.served_tail`` runs the decode as the
+  prologue of the tail program (the AE passed as an argument).
 * **byte layer** (:func:`frame_arrays` / :func:`to_bytes` /
   :func:`from_bytes`) — the self-describing framing.  ``frame_arrays``
-  is the zero-copy path: the header is written *around* the kernel's
-  int8 + scales output (one ``b"".join`` over buffer views, no
-  intermediate numpy copies), and ``from_bytes`` parses into views over
-  the received buffer.
+  writes the header *around* the codec's int8 + scales output (one
+  ``b"".join`` over buffer views, no intermediate numpy copies), and
+  ``from_bytes`` parses into views over the received buffer.
 
 Two frame versions share one parser:
 
@@ -146,11 +144,12 @@ def decode_arrays(kind: str, data: jax.Array, scales: Optional[jax.Array],
     return z.reshape(shape)
 
 
-# The byte path runs the SAME compiled codec math as the fused segments.
-# This is what makes ``fused == eager`` hold to the bit: op-by-op dispatch
-# and XLA compile constant divisions differently (1-ulp scale drift), so
-# both paths must go through one jitted core.  ``ae`` is a pytree argument
-# (no retrace per table entry); ``quantize``/``backend`` are static.
+# The byte path runs the codec math compiled, as ``Partition.served_tail``
+# runs its decode prologue: op-by-op dispatch and XLA compile constant
+# divisions differently (1-ulp scale drift), so the eager decode matches
+# the served tail's to the bit only through one jitted core.  ``ae`` is a
+# pytree argument (no retrace per table entry); ``quantize``/``backend``
+# are static.
 @functools.partial(jax.jit, static_argnames=("quantize", "backend"))
 def _encode_jit(f, ae, *, quantize: bool, backend: Optional[str]):
     return encode_arrays(f, ae, quantize=quantize, backend=backend)
@@ -163,8 +162,7 @@ def _decode_jit(kind: str, data, scales, ae, *, backend: Optional[str]):
 
 # ----------------------------------------------------------- encode side ----
 def encode_activation(f: jax.Array, ae: Optional[dict] = None, *,
-                      quantize: bool = True,
-                      backend: Optional[str] = None) -> WirePacket:
+                      quantize: bool = True) -> WirePacket:
     """Edge-side codec: boundary activation -> wire packet.
 
     ``ae`` present: AE-encoder + int8 (kind ``ae8``, the compressed wire of
@@ -172,7 +170,7 @@ def encode_activation(f: jax.Array, ae: Optional[dict] = None, *,
     (kind ``int8``) or raw f32 when ``quantize=False``.
     """
     kind = wire_kind(ae, quantize)
-    data, scales = _encode_jit(f, ae, quantize=quantize, backend=backend)
+    data, scales = _encode_jit(f, ae, quantize=quantize, backend=None)
     return WirePacket(kind, tuple(data.shape), np.asarray(data),
                       None if scales is None else np.asarray(scales))
 
@@ -209,7 +207,7 @@ def _buffer_view(a, dtype) -> memoryview:
 
 
 def frame_arrays(kind: str, data, scales=None, *, checksum: bool = False) -> bytes:
-    """Zero-copy framing of the jitted path's wire tensors.
+    """Zero-copy framing of the codec's wire tensors.
 
     Writes the self-describing header *around* the kernel's
     ``(data, scales)`` output: the only copy is the single ``join`` into
@@ -241,16 +239,6 @@ def to_bytes(pkt: WirePacket, *, checksum: Optional[bool] = None) -> bytes:
     if checksum is None:
         checksum = pkt.checksum
     return frame_arrays(pkt.kind, pkt.data, pkt.scales, checksum=checksum)
-
-
-def parse_arrays(buf: bytes) -> tuple:
-    """Wire bytes -> device-resident ``(data, scales)`` boundary pytree —
-    the input of a fused segment.  The mirror of :func:`frame_arrays`;
-    callers must re-parse per call when feeding donating segments (the
-    arrays are consumed)."""
-    pkt = from_bytes(buf)
-    return (jnp.asarray(pkt.data),
-            None if pkt.scales is None else jnp.asarray(pkt.scales))
 
 
 def _need(buf, end: int, what: str, off: int):

@@ -169,14 +169,6 @@ ACT_SPECS = {
     "logits": lambda dp: [P(dp, None, "model")],
     "decode_residual": lambda dp: [P("data", None, None)],
     "decode_logits": lambda dp: [P("data", "model")],
-    # wire boundary tensors (runtime.partition fused segments): the int8
-    # codes (N, L) and their (N, 1) row scales shard over the batch-row
-    # axis only — the latent dim stays whole so a row's codes and its
-    # scale land on the same shard and framing needs no gather beyond
-    # the batch axis.  Rank-agnostic (trailing dims replicate), so the
-    # same rule covers flattened (N, L) and full (B, *spatial, L) codes.
-    "boundary_codes": lambda dp: [P(dp)],
-    "boundary_scales": lambda dp: [P(dp)],
 }
 
 

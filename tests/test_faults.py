@@ -97,11 +97,6 @@ class TestWireHardening:
         with pytest.raises(ValueError, match="magic"):
             W.from_bytes(b"NOPE" + b"\x00" * 16)
 
-    def test_parse_arrays_bounds_checked(self):
-        _, buf = self._frame(False)
-        with pytest.raises(W.WireError, match="offset"):
-            W.parse_arrays(buf[:10])
-
 
 # ----------------------------------------------------------- fault plan ----
 class TestFaultPlan:

@@ -19,24 +19,34 @@ from repro.runtime.partition import device_weights, make_partition
 _CONST = re.compile(r"stablehlo\.constant .*: tensor<([0-9x]*)[a-z]")
 
 
-def _ae_partition(model, params):
-    cut = model.cut_points()[3]
+def _ae_partition(model, params, n_cuts: int = 1):
+    cuts = model.cut_points()
+    splits = (cuts[3],) if n_cuts == 1 else (cuts[1], cuts[3])
     ae = B.init_bottleneck(jax.random.PRNGKey(1),
-                           model.activation_shapes(params, 1)[cut], rate=0.5)
-    return make_partition(model, params, cut, ae)
+                           model.activation_shapes(params, 1)[splits[0]],
+                           rate=0.5)
+    return make_partition(model, params, splits, ae)
+
+
+def _boundary(part, x):
+    """The ae8 wire tensors of ``x`` at the first cut, on the device."""
+    return jax.jit(W.encode_arrays)(part.head(x), part.ae)
 
 
 def _scaled(params, k):
     return jax.tree.map(lambda v: v * k, params)
 
 
-def _lowered(part, which: str, x):
-    boundary = part.fused_segments()[0](x)
+def _lowered(model, params, which: str, x):
+    if which == "multi_cut_tail":
+        part = _ae_partition(model, params, n_cuts=2)
+        return part._tail.lower(part.head(x))
+    part = _ae_partition(model, params)
     if which == "served_tail":
-        return part.served_tail("ae8").lower(boundary)
-    if which == "stage":
-        return part.stage(1).lower(part.head(x))
-    return part.fused_segments()[-1].lower(boundary)
+        return part.served_tail("ae8").lower(_boundary(part, x))
+    if which == "head":
+        return part.stage(0).lower(x)
+    return part.stage(1).lower(part.head(x))
 
 
 def _largest_constant(text: str) -> int:
@@ -45,16 +55,16 @@ def _largest_constant(text: str) -> int:
     return max(sizes, default=0)
 
 
-@pytest.mark.parametrize("which", ["served_tail", "stage", "last_segment"])
+@pytest.mark.parametrize("which", ["served_tail", "stage", "multi_cut_tail",
+                                   "head"])
 def test_no_weight_is_compiled_in(vgg_small, toy_data, which):
     model, params = vgg_small
     x = jnp.asarray(toy_data[0][:2])
     smallest_kernel = min(int(np.prod(p["w"].shape)) for p in params
                           if "w" in p)
-    text = _lowered(_ae_partition(model, params), which, x).as_text()
+    text = _lowered(model, params, which, x).as_text()
     assert _largest_constant(text) < smallest_kernel
-    rescaled = _lowered(_ae_partition(model, _scaled(params, 3.0)), which,
-                        x).as_text()
+    rescaled = _lowered(model, _scaled(params, 3.0), which, x).as_text()
     assert len(rescaled) == len(text)
     assert rescaled == text
 
@@ -68,14 +78,13 @@ def test_served_tail_donates_the_boundary_only(vgg_small, toy_data,
     model, params = vgg_small
     part = _ae_partition(model, params)
     x = jnp.asarray(toy_data[0][:2])
-    seg0 = part.fused_segments()[0]
     prog = part.served_tail("ae8")
-    (w_info, ae_info, bd_info), _ = prog.lower(seg0(x)).args_info
+    (w_info, ae_info, bd_info), _ = prog.lower(_boundary(part, x)).args_info
     assert not any(a.donated for a in jax.tree.leaves(w_info))
     assert not any(a.donated for a in jax.tree.leaves(ae_info))
     assert all(a.donated for a in jax.tree.leaves(bd_info))
-    first = np.asarray(prog(seg0(x)))
-    second = np.asarray(prog(seg0(x)))
+    first = np.asarray(prog(_boundary(part, x)))
+    second = np.asarray(prog(_boundary(part, x)))
     np.testing.assert_array_equal(first, second)
     live = jax.tree.leaves(part._weights()) + jax.tree.leaves(part.ae)
     assert live and not any(a.is_deleted() for a in live)
@@ -134,5 +143,7 @@ def test_served_tail_under_highest_equals_tail_of_decode(vgg_small, toy_data):
     with jax.default_matmul_precision("highest"):
         want = np.asarray(part.tail(W.decode_activation(W.from_bytes(buf),
                                                         part.ae)))
-        got = np.asarray(part.served_tail("ae8")(W.parse_arrays(buf)))
+        pkt = W.from_bytes(buf)
+        got = np.asarray(part.served_tail("ae8")(
+            (jnp.asarray(pkt.data), jnp.asarray(pkt.scales))))
     np.testing.assert_allclose(got, want, atol=1e-5)

@@ -158,7 +158,42 @@ def test_runtime_int8_wire_close_and_timed(vgg_small, toy_data):
     assert res.wire_bytes < raw.wire_bytes / 2
 
 
-def test_total_s_is_transfer_inclusive_and_reconciles(vgg_small, toy_data):
+def _ae_for(model, params, cut, rate=0.5):
+    shapes = model.activation_shapes(params, 1)
+    return B.init_bottleneck(jax.random.PRNGKey(1), shapes[cut], rate=rate)
+
+
+@pytest.mark.parametrize("ae_at,quantize", [(0, True), (0, False), (1, True)],
+                         ids=["ae_first", "ae_first_f32", "ae_second"])
+def test_runtime_hops_match_hand_chain(vgg_small, toy_data, ae_at, quantize):
+    """On a 2-cut partition ``SplitRuntime.infer`` is the hand-run chain
+    stage -> to_bytes -> from_bytes -> decode_activation, bit for bit:
+    the same logits and the same bytes on every hop, across ae8, int8
+    and f32 payloads."""
+    model, params = vgg_small
+    xs, _ = toy_data
+    x = jnp.asarray(xs[:4])
+    cuts = model.cut_points()
+    splits = (cuts[1], cuts[3])
+    ae = {splits[ae_at]: _ae_for(model, params, splits[ae_at])}
+    rt = SplitRuntime(model, params, splits, ae=ae, quantize=quantize)
+    res = rt.infer(x, iters=1)
+    part, cur, nbytes = rt.part, x, []
+    for k, cut in enumerate(splits):
+        cur = part.stage(k)(cur)
+        buf = W.to_bytes(W.encode_activation(cur, ae.get(cut),
+                                             quantize=quantize))
+        nbytes.append(len(buf))
+        cur = W.decode_activation(W.from_bytes(buf), ae.get(cut))
+    want = np.asarray(part.stage(len(splits))(cur))
+    np.testing.assert_array_equal(res.logits, want)
+    assert [h["bytes"] for h in res.hops] == nbytes
+    assert res.wire_bytes == sum(nbytes)
+
+
+@pytest.mark.parametrize("n_cuts", [1, 2])
+def test_total_s_is_transfer_inclusive_and_reconciles(vgg_small, toy_data,
+                                                      n_cuts):
     """Regression pin: ``RuntimeResult.total_s`` includes the netsim-priced
     transfer time (``compute_s + transfer_s``) and reconciles exactly with
     the per-stage/per-hop breakdown and the ``build_infer_spans`` root —
@@ -169,8 +204,11 @@ def test_total_s_is_transfer_inclusive_and_reconciles(vgg_small, toy_data):
     xs, _ = toy_data
     # a slow, high-latency link so transfer dominates unambiguously
     ch = Channel(latency_s=0.05, capacity_bps=1e6, interface_bps=1e6)
-    rt = SplitRuntime(model, params, model.cut_points()[2], channel=ch)
+    cuts = model.cut_points()
+    splits = cuts[2] if n_cuts == 1 else (cuts[1], cuts[3])
+    rt = SplitRuntime(model, params, splits, channel=ch)
     res = rt.infer(xs[:2], iters=1)
+    assert len(res.stage_s) == n_cuts + 1 and len(res.hops) == n_cuts
     assert res.transfer_s > 0
     assert res.total_s == res.compute_s + res.transfer_s
     assert res.total_s > res.compute_s          # the transfer is in there
@@ -227,6 +265,25 @@ def test_calibration_table_roundtrip(tmp_path, cal_setup):
     assert back.lookup("SC", splits[0]) == table.lookup("SC", splits[0])
     assert back.lookup("RC").server_s > 0
     assert back.lookup("LC").edge_s > 0
+
+
+def test_calibrate_quotes_head_plus_codec_costs(vgg_small, tmp_path):
+    """An SC entry prices the edge as head + encode and the server as
+    decode + tail, quotes exactly that through ``flow_times``, and comes
+    back from JSON unchanged."""
+    model, params = vgg_small
+    c0 = model.cut_points()[1]
+    t = calibrate(model, params, [c0], ae_map={c0: _ae_for(model, params, c0)},
+                  batch=2, iters=1)
+    e = t.lookup("SC", c0)
+    assert e.head_s > 0 and e.encode_s > 0 and e.decode_s > 0
+    assert e.edge_s == e.head_s + e.encode_s
+    assert e.server_s == e.decode_s + e.tail_s
+    ft = t.flow_times("SC", c0)
+    assert (ft["edge_s"], ft["server_s"]) == (e.edge_s, e.server_s)
+    p = str(tmp_path / "cal.json")
+    t.to_json(p)
+    assert CalibrationTable.from_json(p).lookup("SC", c0) == e
 
 
 def test_measured_flow_uses_calibration(cal_setup):
